@@ -90,6 +90,7 @@ from __future__ import annotations
 import argparse
 import functools
 import json
+import os
 import time
 from typing import Dict, Optional
 
@@ -554,7 +555,7 @@ def experiments_campaign_throughput(n_clones: int = 6) -> None:
     import tempfile
 
     from repro.core.distributed import kernel_cache_clear
-    from repro.experiments import run_campaign
+    from repro.experiments import enable_persistent_cache, run_campaign
 
     base = get_scenario("rram_smoke")
     clones = [dataclasses.replace(base, name=f"rram_smoke_clone{i}")
@@ -568,22 +569,27 @@ def experiments_campaign_throughput(n_clones: int = 6) -> None:
     t_seq = time.perf_counter() - t0
 
     with tempfile.TemporaryDirectory() as cache_dir:
+        prior = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         try:
+            enable_persistent_cache()
             kernel_cache_clear()
             jax.clear_caches()
             t0 = time.perf_counter()
-            _, stats = run_campaign(clones, write=False,
-                                    compile_cache=cache_dir)
+            _, stats = run_campaign(clones, write=False)
             t_camp = time.perf_counter() - t0
 
             kernel_cache_clear()
             jax.clear_caches()
             t0 = time.perf_counter()
-            _, stats_warm = run_campaign(clones, write=False,
-                                         compile_cache=cache_dir)
+            _, stats_warm = run_campaign(clones, write=False)
             t_warm = time.perf_counter() - t0
         finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+            if prior is None:
+                del os.environ["JAX_COMPILATION_CACHE_DIR"]
+            else:
+                os.environ["JAX_COMPILATION_CACHE_DIR"] = prior
+            jax.config.update("jax_compilation_cache_dir", prior)
 
     speedup = t_seq / t_camp
     pc = stats_warm["persistent_cache"]

@@ -159,9 +159,11 @@ def _cost_core(space: SearchSpace, c: HWConstants, p: Dict[str, jax.Array],
         cpw_ = jnp.ceil(wbits / bits_cell[:, None])    # per-layer cells
 
     def sum_l(x):                                               # (P, W)
-        if mask is None:
-            return x @ seg_onehot
-        return (x * mask) @ seg_onehot
+        # HIGHEST: a TPU's default f32 matmul rounds x to bf16
+        if mask is not None:
+            x = x * mask
+        return jnp.matmul(x, seg_onehot,
+                          precision=jax.lax.Precision.HIGHEST)
 
     n_xb_row = jnp.ceil(K / r_)
     n_xb_col = jnp.ceil(N * cpw_ / c_)

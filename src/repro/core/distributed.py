@@ -98,11 +98,13 @@ def compile_batched_search(search_one: Callable, mesh: Optional[Mesh] = None,
     ``search_one`` is a traceable kernel ``key -> pytree of arrays``
     (core.genetic.search_kernel closed over its schedule/scorer); the
     returned callable maps a (S, key) batch to the stacked results.
-    With a ``mesh``, the search axis is sharded over ``axis``: every
-    device runs S/axis_size whole searches with zero inter-device
-    communication (searches are independent by construction). The axis
-    size must then divide S; callers fall back to mesh=None otherwise
-    (see experiments/runner._search_mesh).
+    With a ``mesh``, the search axis is sharded over ``axis`` by
+    ``shard_map``: every device runs S/axis_size whole searches with
+    zero inter-device communication (searches are independent by
+    construction), so the partitioner never splits a search, nor a
+    Pallas kernel inside one. The axis size must then divide S; callers
+    fall back to mesh=None otherwise (see experiments/runner._search_mesh
+    and the campaign's device-rounded lane tiers).
 
     ``donate=True`` donates every input buffer (lane keys, padded
     schedules, masks) to the computation — callers must pass freshly
@@ -119,4 +121,9 @@ def compile_batched_search(search_one: Callable, mesh: Optional[Mesh] = None,
     if mesh is None:
         return jax.jit(fn, **kw)
     sh = NamedSharding(mesh, P(axis))
-    return jax.jit(fn, in_shardings=sh, out_shardings=sh, **kw)
+    # check_vma=False: the scanned searches build their carries from
+    # unsharded constants, which the varying-axis check rejects, though
+    # every lane's search is local to its device by construction
+    local = jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
+                          out_specs=P(axis), check_vma=False)
+    return jax.jit(local, in_shardings=sh, out_shardings=sh, **kw)

@@ -162,6 +162,12 @@ def flat_index_strides(space: SearchSpace) -> np.ndarray:
         [np.cumprod(cards[::-1])[::-1][1:], [1]]).astype(np.int32)
 
 
+def _flat_index(genomes: jax.Array, strides: jax.Array) -> jax.Array:
+    # an elementwise product and sum, not an int32 matmul: exact on
+    # every backend (the index keys each design's noise draw)
+    return jnp.sum(genomes * strides, axis=-1)
+
+
 def genome_flat_index(space: SearchSpace, genomes: jax.Array) -> jax.Array:
     """(P, n) index genomes -> (P,) unique flat (mixed-radix) index.
 
@@ -170,7 +176,7 @@ def genome_flat_index(space: SearchSpace, genomes: jax.Array) -> jax.Array:
     2^31 (paper: <= 1.21e7), so int32 is safe. Host-facing convenience;
     the accuracy model's traced closure precomputes the strides once
     via ``flat_index_strides``."""
-    return genomes @ jnp.asarray(flat_index_strides(space))
+    return _flat_index(genomes, jnp.asarray(flat_index_strides(space)))
 
 
 def _workload_accuracy_params(
@@ -197,6 +203,13 @@ def _snr_to_accuracy(snr_db: jax.Array, base: jax.Array,
                      depth_pen: jax.Array) -> jax.Array:
     keep = jax.nn.sigmoid((snr_db - _SNR_MID_DB) / _SNR_SCALE_DB)
     return base * (_ACC_FLOOR + (1.0 - _ACC_FLOOR) * keep) * depth_pen
+
+
+def _clean_gemm(x_q: jax.Array, w: jax.Array) -> jax.Array:
+    """The noise-free quantized calibration GEMM, the SNR reference.
+    HIGHEST: a TPU's default f32 matmul rounds ``w`` to bf16."""
+    return jnp.matmul(x_q.astype(jnp.float32), w,
+                      precision=jax.lax.Precision.HIGHEST) / 255.0
 
 
 def calibration_data(key: jax.Array, n_calib: int, calib_k: int,
@@ -255,7 +268,7 @@ def make_accuracy_model(space: SearchSpace,
     k_calib, k_noise = jax.random.split(key)
     x, w = calibration_data(k_calib, n_calib, calib_k, calib_n)
     x_q = quantize_activations(x)
-    y_ref = (x_q.astype(jnp.float32) @ w) / 255.0  # clean quantized GEMM
+    y_ref = _clean_gemm(x_q, w)
 
     table = jnp.asarray(space.value_table())
     rows_i = space.index("xbar_rows")
@@ -292,11 +305,13 @@ def make_accuracy_model(space: SearchSpace,
         w_eff = _noised_weights(k_pos, k_neg, w, rows)
         wt = jnp.pad(w_eff, ((0, pad), (0, 0))).reshape(n_sub, sub, -1)
         # (8, B, n_sub, N) per-sub-tile bit-plane partial sums
-        partial = jnp.einsum("qbsk,skn->qbsn", planes, wt)
+        partial = jnp.einsum("qbsk,skn->qbsn", planes, wt,
+                             precision=jax.lax.Precision.HIGHEST)
         # sum sub-tiles into crossbars of `rows` rows (traced grouping)
         grp = jnp.floor(sub_rows / rows)
         onehot = (grp[:, None] == group_idx[None, :]).astype(jnp.float32)
-        tiles = jnp.einsum("qbsn,sg->qbgn", partial, onehot)
+        tiles = jnp.einsum("qbsn,sg->qbgn", partial, onehot,
+                           precision=jax.lax.Precision.HIGHEST)
         q = adc_quantize(tiles, adc_full_scale(rows), adc_bits)
         y = jnp.sum(q * pow2[:, None, None, None], axis=(0, 2)) / 255.0
         y = y + OUTPUT_NOISE_FRAC * jnp.std(y) * \
@@ -352,7 +367,7 @@ def make_accuracy_model(space: SearchSpace,
     @traced_closure
     def accuracy(genomes: jax.Array) -> jax.Array:
         genomes = jnp.asarray(genomes)
-        flat = genomes @ strides
+        flat = _flat_index(genomes, strides)
         if backend == "jnp":
             snr_db = batched(genomes, flat)
         else:
@@ -386,7 +401,7 @@ def accuracy_proxy_host(space: SearchSpace, genomes: np.ndarray,
     k_calib, k_noise = jax.random.split(key)
     x, w = calibration_data(k_calib, n_calib, calib_k, calib_n)
     x_q = quantize_activations(x)
-    y_ref = (x_q.astype(jnp.float32) @ w) / 255.0
+    y_ref = _clean_gemm(x_q, w)
 
     genomes = np.asarray(genomes)
     table = space.value_table()

@@ -4,7 +4,7 @@
   python -m repro.experiments show --scenario rram_small_set
   python -m repro.experiments run --scenario rram_small_set \
       [--out DIR] [--seed N] [--seeds S] [--force] [--smoke]
-      [--backend auto|pallas|ref|jnp] [--campaign] [--compile-cache DIR]
+      [--backend auto|pallas|ref|jnp] [--campaign]
   python -m repro.experiments run --all [--out DIR] [--sequential]
   python -m repro.experiments report [--out DIR]
 
@@ -14,8 +14,10 @@ aggregates every cached result into ``summary.md`` — the regenerated
 paper tables. README.md maps each paper table to its scenario names.
 
 ``run --all`` routes through the campaign engine (campaign.py): shape-
-bucketed mega-batched scenario execution with async pipelining, plus
-an optional persistent compilation cache (``--compile-cache DIR``).
+bucketed mega-batched scenario execution with async pipelining. ``run``
+keeps compiled kernels in jax's persistent compilation cache, at
+``JAX_COMPILATION_CACHE_DIR`` when set, else at the checkout's
+``.jax_compile_cache/``.
 Results are byte-identical to sequential execution (modulo timing
 fields); ``--sequential`` restores the old one-scenario-at-a-time
 loop.
@@ -91,24 +93,22 @@ def cmd_run(args) -> int:
     if not args.all and args.scenario is None:
         print("run: pass --scenario NAME or --all", file=sys.stderr)
         return 2
+    scenarios = [_prepare(args, n) for n in names]
+    from . import campaign
+    campaign.enable_persistent_cache()
     use_campaign = ((args.all or args.campaign)
                     and not args.sequential)
     if use_campaign:
-        from . import campaign
         results, stats = campaign.run_campaign(
-            [_prepare(args, n) for n in names], out_dir=args.out,
-            force=args.force, seed=args.seed, n_seeds=args.seeds,
-            compile_cache=args.compile_cache)
+            scenarios, out_dir=args.out, force=args.force,
+            seed=args.seed, n_seeds=args.seeds)
         for name, res in zip(names, results):
             _print_result(name, res, args.out)
         _print_campaign_stats(stats, args.out)
         return 0
-    if args.compile_cache:
-        from . import campaign
-        campaign.enable_persistent_cache(args.compile_cache)
-    for name in names:
+    for name, sc in zip(names, scenarios):
         res = runner.run_scenario(
-            _prepare(args, name), out_dir=args.out, force=args.force,
+            sc, out_dir=args.out, force=args.force,
             seed=args.seed, n_seeds=args.seeds)
         _print_result(name, res, args.out)
     return 0
@@ -209,11 +209,6 @@ def main(argv=None) -> int:
                         "strictly sequentially (the pre-campaign "
                         "behaviour; results are identical modulo "
                         "timing fields)")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persist XLA-compiled kernels under DIR "
-                        "(jax compilation cache): repeated invocations "
-                        "skip compile entirely; nightly CI persists "
-                        "this directory across runs")
     p.set_defaults(fn=cmd_run)
 
     p = sub.add_parser("report", help="aggregate results into summary.md")

@@ -36,7 +36,8 @@ device call:
   (replicated lane 0, sliced off on drain) so bucket batches of
   nearby sizes reuse one executable shape.
 * **persistent compilation cache** — ``enable_persistent_cache`` wires
-  jax's on-disk compilation cache (so repeated CLI invocations and
+  jax's on-disk compilation cache at ``JAX_COMPILATION_CACHE_DIR`` or,
+  unset, a fixed in-checkout directory (so repeated CLI invocations and
   nightly CI skip XLA compile entirely) plus a small JSON index keyed
   by bucket signature whose hit/miss counters surface in the campaign
   stats.
@@ -98,8 +99,11 @@ def gen_tier(t: int) -> int:
 
 def lane_tier(b: int) -> int:
     """Smallest batch-lane tier >= b (multiples of 128 past the
-    ladder)."""
-    return _tier(b, LANE_TIERS, 128)
+    ladder), rounded up to a multiple of the device count so that every
+    bucket shards over all devices (runner._search_mesh shards only a
+    lane count the device count divides)."""
+    n_dev = jax.device_count()
+    return -(-_tier(b, LANE_TIERS, 128) // n_dev) * n_dev
 
 
 def scorer_key(scenario: Scenario) -> Tuple:
@@ -528,12 +532,30 @@ def execute_buckets(buckets: Sequence[_Bucket],
 
 _INDEX_NAME = "campaign_index.json"
 
+# <checkout>/.jax_compile_cache: derived from this package's location
+# (src/repro/experiments/), so it is the same path on every run and a
+# cache written by one run is found by the next.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))),
+    ".jax_compile_cache")
 
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Point jax's on-disk compilation cache at ``cache_dir`` (created
-    if missing) with thresholds dropped to cache every search kernel.
-    Returns the path of the campaign's bucket-signature index inside
-    it. Safe to call repeatedly / first thing in a process."""
+
+def compile_cache_dir() -> str:
+    """Where the persistent compilation cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else the
+    fixed in-checkout ``DEFAULT_CACHE_DIR``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache() -> str:
+    """Point jax's on-disk compilation cache at ``compile_cache_dir()``
+    (created if missing) with thresholds dropped to cache every search
+    kernel. Called once by each entry point (experiments CLI, service
+    launcher, chip smoke); ``run_campaign`` keeps its bucket-signature
+    index next to the cache whenever one is enabled. Returns the
+    index's path."""
+    cache_dir = compile_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -566,7 +588,6 @@ def run_campaign(scenarios: Sequence[Scenario],
                  out_dir: str = runner.DEFAULT_OUT_DIR,
                  force: bool = False, seed: Optional[int] = None,
                  n_seeds: Optional[int] = None, write: bool = True,
-                 compile_cache: Optional[str] = None,
                  window: int = 2,
                  specific_fanout: bool = True,
                  ) -> Tuple[List[Dict], Dict]:
@@ -574,15 +595,15 @@ def run_campaign(scenarios: Sequence[Scenario],
 
     Returns (results in input order, campaign stats). ``window`` is
     the pipelining depth: how many buckets may be in flight before the
-    oldest is drained. ``compile_cache`` enables the persistent XLA
-    compilation cache at that directory. Stats are written to
+    oldest is drained. The bucket-signature index lives in the
+    persistent compilation cache's directory when an entry point has
+    enabled one (``enable_persistent_cache``). Stats are written to
     ``<out_dir>/campaign_stats.json`` when ``write``.
     """
     t_start = time.perf_counter()
-    index_path = None
-    if compile_cache:
-        index_path = enable_persistent_cache(compile_cache)
-    entries_before = _cache_entries(compile_cache)
+    cache_dir = jax.config.jax_compilation_cache_dir
+    index_path = os.path.join(cache_dir, _INDEX_NAME) if cache_dir else None
+    entries_before = _cache_entries(cache_dir)
     kstats0 = kernel_cache_stats()
 
     jobs = plan_campaign(scenarios, out_dir=out_dir, force=force,
@@ -635,10 +656,10 @@ def run_campaign(scenarios: Sequence[Scenario],
             k: kstats1[k] - kstats0.get(k, 0)
             for k in ("hits", "misses", "evictions")},
         "persistent_cache": {
-            "enabled": bool(compile_cache),
-            "dir": compile_cache,
+            "enabled": bool(cache_dir),
+            "dir": cache_dir,
             "entries_before": entries_before,
-            "entries_after": _cache_entries(compile_cache),
+            "entries_after": _cache_entries(cache_dir),
             "signature_hits": sig_hits,
             "signature_misses": sig_misses,
         },

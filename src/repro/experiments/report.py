@@ -618,8 +618,8 @@ def render_campaign_stats(stats: Dict) -> str:
             f"{pc['entries_after'] - pc['entries_before']} new "
             f"entries ({pc['entries_after']} total)")
     else:
-        lines.append("- persistent XLA cache: disabled "
-                     "(pass --compile-cache DIR)")
+        lines.append("- persistent XLA cache: disabled (library call; "
+                     "the CLI enables it)")
     lines += [
         "",
         "| bucket | engine | scenarios | lanes | gen tier | "

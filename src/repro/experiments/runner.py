@@ -1034,6 +1034,11 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
 
     result["seeds"] = report.aggregate_seeds(seeds, best_scores,
                                              gap_means)
+    if not is_mo:
+        # each seed's best genome (search-space indices), so a seed's
+        # best score can be re-scored elsewhere (chip_smoke.py)
+        result["seeds"]["best_genome"] = {
+            "per_seed": np.asarray(res.best_genomes).tolist()}
     result["wall_time_s"] = time.perf_counter() - t0
     if write:
         report.write_artifacts(result, sdir)

@@ -35,7 +35,9 @@ not import core.
 
 Validated in interpret mode against ref.imc_fused_ref
 (tests/test_kernels.py) and against the pre-existing einsum path on
-every registry calibration config (tests/test_nonideal.py).
+every registry calibration config (tests/test_nonideal.py); compiled
+for a described TPU v5e by tests/test_tpu_compile.py, and checked on
+the chip against the CPU oracle by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -80,11 +82,13 @@ def _sigma_scalar(g: jax.Array) -> jax.Array:
                     0.0, 0.5)
 
 
-def _fused_kernel(idx_ref, table_ref, x_ref, w_ref, ep_ref, en_ref,
+def _fused_kernel(idx_ref, table_ref, xt_ref, w_ref, ep_ref, en_ref,
                   o_ref, acc_ref, *, sub: int, n_sub: int, adc_bits: int):
-    s = pl.program_id(1)
-    # value-table gather: the genome's crossbar row count
-    rows = table_ref[idx_ref[0]]
+    p, s = pl.program_id(0), pl.program_id(1)
+    # value-table gather (scalar prefetch, SMEM): the genome's integer
+    # crossbar row count
+    rows_i = table_ref[idx_ref[p]]
+    rows = rows_i.astype(jnp.float32)
 
     @pl.when(s == 0)
     def _init():
@@ -101,27 +105,34 @@ def _fused_kernel(idx_ref, table_ref, x_ref, w_ref, ep_ref, en_ref,
     w_eff = (g_pos - g_neg) * ir_drop_factor(rows)
 
     # bit-serial partial sums of this sub-tile into the running group
-    # accumulator (8, B, N)
-    x = x_ref[...]
+    # accumulator (8, B, N). The activations arrive transposed, (sub, B),
+    # so the sub-tile is a sublane slice: a (B, sub) block would put
+    # sub (a gcd, often 64) on the 128-wide lane axis. HIGHEST keeps the
+    # f32 contraction exact on the MXU (the default rounds w_eff to bf16).
+    xt = xt_ref[...]
     for b in range(WEIGHT_BITS):
-        bit = ((x >> b) & 1).astype(jnp.float32)
+        bit = ((xt >> b) & 1).astype(jnp.float32)
         acc_ref[b] += jax.lax.dot_general(
-            bit, w_eff, (((1,), (0,)), ((), ())),
+            bit, w_eff, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     # crossbar-group boundary: the next sub-tile belongs to a new
-    # physical crossbar of `rows` rows (traced, genome-dependent)
-    s_f = jnp.float32(s)
+    # physical crossbar of `rows` rows (traced, genome-dependent);
+    # integer floor division equals the oracle's float floor here
     group_end = jnp.logical_or(
         s == n_sub - 1,
-        jnp.floor((s_f + 1.0) * float(sub) / rows)
-        != jnp.floor(s_f * float(sub) / rows))
+        (s + 1) * sub // rows_i != s * sub // rows_i)
 
     @pl.when(group_end)
     def _flush():
         q = adc_quantize(acc_ref[...], adc_full_scale(rows), adc_bits)
-        pow2 = 2.0 ** jnp.arange(WEIGHT_BITS, dtype=jnp.float32)
-        o_ref[0] += jnp.sum(q * pow2[:, None, None], axis=0)
+        # shift-accumulate; every term is an exact multiple of the ADC
+        # step, so the unrolled sum equals the oracle's reduction
+        tot = q[0]
+        for b in range(1, WEIGHT_BITS):
+            tot = tot + q[b] * float(2 ** b)
+        o_ref[0] += tot
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
@@ -135,8 +146,9 @@ def imc_fused_gemm(x_q: jax.Array, w: jax.Array, eps_pos: jax.Array,
     x_q: (B, K) int32 activation codes in [0, 255] (shared by every
     genome); w: (K, N) f32 target weights in [-1, 1]; eps_pos/eps_neg:
     (P, K, N) per-genome standard-normal conductance-noise fields;
-    rows_idx: (P,) int32 indices into ``row_table`` ((V,) f32 crossbar
-    row counts — gathered in-kernel). Returns the (P, B, N)
+    rows_idx: (P,) int32 indices into ``row_table`` ((V,) integer-valued
+    crossbar row counts — both scalar-prefetched to SMEM and gathered
+    in-kernel). Returns the (P, B, N)
     shift-accumulated ADC-quantized column sums at the analog code
     scale (divide by 255 for the activation scale, as in
     imc_matmul_ref). K is padded to a multiple of ``sub`` here; callers
@@ -155,20 +167,23 @@ def imc_fused_gemm(x_q: jax.Array, w: jax.Array, eps_pos: jax.Array,
         interpret = jax.default_backend() == "cpu"
     kernel = functools.partial(_fused_kernel, sub=sub, n_sub=n_sub,
                                adc_bits=adc_bits)
-    return pl.pallas_call(
-        kernel,
+    # index maps receive the two scalar-prefetch refs after the grid ids
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(P, n_sub),
         in_specs=[
-            pl.BlockSpec((1,), lambda p, s: (p,)),
-            pl.BlockSpec((row_table.shape[0],), lambda p, s: (0,)),
-            pl.BlockSpec((B, sub), lambda p, s: (0, s)),
-            pl.BlockSpec((sub, N), lambda p, s: (s, 0)),
-            pl.BlockSpec((1, sub, N), lambda p, s: (p, s, 0)),
-            pl.BlockSpec((1, sub, N), lambda p, s: (p, s, 0)),
+            pl.BlockSpec((sub, B), lambda p, s, *_: (s, 0)),
+            pl.BlockSpec((sub, N), lambda p, s, *_: (s, 0)),
+            pl.BlockSpec((1, sub, N), lambda p, s, *_: (p, s, 0)),
+            pl.BlockSpec((1, sub, N), lambda p, s, *_: (p, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, B, N), lambda p, s: (p, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, B, N), jnp.float32),
+        out_specs=pl.BlockSpec((1, B, N), lambda p, s, *_: (p, 0, 0)),
         scratch_shapes=[pltpu.VMEM((WEIGHT_BITS, B, N), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, B, N), jnp.float32),
         interpret=interpret,
-    )(rows_idx.astype(jnp.int32), row_table.astype(jnp.float32),
-      x_q.astype(jnp.int32), w.astype(jnp.float32), eps_pos, eps_neg)
+    )(rows_idx.astype(jnp.int32), row_table.astype(jnp.int32),
+      x_q.astype(jnp.int32).T, w.astype(jnp.float32), eps_pos, eps_neg)

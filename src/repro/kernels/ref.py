@@ -25,7 +25,8 @@ def imc_matmul_ref(x_q: jax.Array, w: jax.Array, *, xbar_rows: int = 256,
     out = jnp.zeros((M, N), jnp.float32)
     for b in range(WEIGHT_BITS):
         bit = ((xt >> b) & 1).astype(jnp.float32)
-        partial = jnp.einsum("mtk,tkn->mtn", bit, wt.astype(jnp.float32))
+        partial = jnp.einsum("mtk,tkn->mtn", bit, wt.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
         q = adc_quantize(partial, full_scale, adc_bits)
         out = out + jnp.sum(q, axis=1) * (2.0 ** b)
     return out
@@ -55,11 +56,13 @@ def imc_fused_ref(x_q: jax.Array, w: jax.Array, eps_pos: jax.Array,
     planes = jnp.stack(
         [((xp >> b) & 1).astype(jnp.float32) for b in range(WEIGHT_BITS)])
     planes = planes.reshape(WEIGHT_BITS, B, n_sub, sub)
-    partial = jnp.einsum("qbsk,skn->qbsn", planes, wt)
+    partial = jnp.einsum("qbsk,skn->qbsn", planes, wt,
+                         precision=jax.lax.Precision.HIGHEST)
     sub_idx = jnp.arange(n_sub, dtype=jnp.float32)
     grp = jnp.floor(sub_idx * float(sub) / rows)
     onehot = (grp[:, None] == sub_idx[None, :]).astype(jnp.float32)
-    tiles = jnp.einsum("qbsn,sg->qbgn", partial, onehot)
+    tiles = jnp.einsum("qbsn,sg->qbgn", partial, onehot,
+                       precision=jax.lax.Precision.HIGHEST)
     q = adc_quantize(tiles, adc_full_scale(rows), adc_bits)
     pow2 = 2.0 ** jnp.arange(WEIGHT_BITS, dtype=jnp.float32)
     return jnp.sum(q * pow2[:, None, None, None], axis=(0, 2))

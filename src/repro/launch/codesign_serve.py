@@ -4,14 +4,18 @@ the end.
 
   python -m repro.launch.codesign_serve --requests 4 --smoke
   python -m repro.launch.codesign_serve --scenario rram_small_set \
-      --requests 8 --smoke --out /tmp/serve --compile-cache ~/.cache/x
+      --requests 8 --smoke --out results/serve
 
 Each request is a distinct clone of the base scenario (its own name
 and seed), so every request owns a result-cache entry. With
 ``--verify-cached`` (default on) the same requests are resubmitted
 after the first round completes and the launcher asserts every
 response is served from the result cache with an identical payload —
-the CI service smoke gate.
+the CI service smoke gate. The launcher exits nonzero when a request
+fails, a replay misses the cache, or any bucket degraded to the
+sequential runner. Compiled kernels persist in jax's compilation cache
+(``JAX_COMPILATION_CACHE_DIR``, else the checkout's
+``.jax_compile_cache/``).
 """
 from __future__ import annotations
 
@@ -20,7 +24,7 @@ import dataclasses
 import sys
 
 from ..api import (DEFAULT_OUT_DIR, CodesignService, SearchRequest,
-                   get_scenario)
+                   enable_persistent_cache, get_scenario)
 
 # result fields that legitimately differ between a fresh run and its
 # cached replay (runner timing + the cache marker itself)
@@ -66,8 +70,6 @@ def main() -> int:
     ap.add_argument("--out", default=DEFAULT_OUT_DIR)
     ap.add_argument("--window", type=float, default=0.25,
                     help="micro-batching window (s)")
-    ap.add_argument("--compile-cache", default=None,
-                    help="persistent XLA compile cache directory")
     ap.add_argument("--no-verify-cached", dest="verify_cached",
                     action="store_false",
                     help="skip the cached-replay verification round")
@@ -78,8 +80,8 @@ def main() -> int:
                                   seed=base.seed + i)
               for i in range(args.requests)]
 
-    with CodesignService(out_dir=args.out, window_s=args.window,
-                         compile_cache=args.compile_cache) as svc:
+    enable_persistent_cache()
+    with CodesignService(out_dir=args.out, window_s=args.window) as svc:
         rids = [svc.submit(SearchRequest(sc, smoke=args.smoke))
                 for sc in clones]
         first = [svc.result(rid, timeout=1800) for rid in rids]
@@ -91,6 +93,12 @@ def main() -> int:
             print(f"FAIL: {len(bad)} request(s) did not complete: "
                   f"{[(r.request_id, r.status, r.error) for r in bad]}")
             print(render_stats(svc.stats()))
+            return 1
+        stats = svc.stats()
+        if stats.degraded_buckets:
+            print(f"FAIL: {stats.degraded_buckets} bucket(s) failed and "
+                  "were re-run through the sequential runner")
+            print(render_stats(stats))
             return 1
 
         if args.verify_cached:

@@ -3,10 +3,6 @@
 Axes: ("pod", "data", "model"). Single pod = 256 chips (16 x 16);
 multi-pod = 2 pods = 512 chips. A FUNCTION (not module-level constant)
 so importing never touches jax device state.
-
-Compatible with both jax API generations: explicit-sharding Auto axis
-types and ``jax.set_mesh`` where available (jax >= 0.5), the plain
-``jax.make_mesh`` + legacy Mesh context manager otherwise.
 """
 from __future__ import annotations
 
@@ -15,20 +11,14 @@ from jax.sharding import Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_context(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` when available, else the legacy Mesh
-    context manager — both scope `in/out_shardings` name resolution."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh(mesh)``: scopes `in/out_shardings` name
+    resolution."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -104,7 +104,6 @@ class CodesignService:
                  window_s: float = 0.05, max_batch: int = 64,
                  pipeline_window: int = 2,
                  specific_fanout: bool = True,
-                 compile_cache: Optional[str] = None,
                  autostart: bool = True):
         self.out_dir = out_dir
         self.write = write
@@ -114,8 +113,6 @@ class CodesignService:
         self.pipeline_window = pipeline_window
         self.specific_fanout = specific_fanout
         self._autostart = autostart
-        if compile_cache:
-            campaign.enable_persistent_cache(compile_cache)
 
         self._cond = threading.Condition(threading.RLock())
         self._queue: "deque[_Record]" = deque()

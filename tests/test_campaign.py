@@ -74,6 +74,15 @@ def test_tiers_cover_and_bound():
             assert t < 2 * n or n == 1
 
 
+def test_lane_tiers_round_to_device_count(monkeypatch):
+    """On a multi-device runtime every bucket's lane count is a multiple
+    of the device count, so no bucket silently runs on one device."""
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    for n in range(1, 300):
+        t = campaign.lane_tier(n)
+        assert t >= n and t % 4 == 0
+
+
 def test_tiers_monotone():
     gens = [campaign.gen_tier(n) for n in range(1, 200)]
     lanes = [campaign.lane_tier(n) for n in range(1, 300)]
@@ -305,21 +314,34 @@ def test_campaign_stats_schema_and_render(tmp_path):
     assert "## Campaign execution" in summary
 
 
-def test_campaign_persistent_cache_index(tmp_path):
+def test_compile_cache_dir_resolution(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins; unset, the cache sits at one fixed
+    path inside the checkout (a moving path would never hit)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert campaign.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first, second = campaign.compile_cache_dir(), campaign.compile_cache_dir()
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first == second == os.path.join(repo_root, ".jax_compile_cache")
+
+
+def test_campaign_persistent_cache_index(tmp_path, monkeypatch):
     cache_dir = str(tmp_path / "xla_cache")
     out = str(tmp_path / "results")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
     try:
+        index = campaign.enable_persistent_cache()
+        assert index == os.path.join(cache_dir, "campaign_index.json")
+        assert jax.config.jax_compilation_cache_dir == cache_dir
         _, s1 = campaign.run_campaign([TINY], out_dir=out, n_seeds=1,
-                                      force=True,
-                                      compile_cache=cache_dir)
+                                      force=True)
         pc1 = s1["persistent_cache"]
         assert pc1["enabled"] and pc1["signature_misses"] == 1
-        assert os.path.exists(os.path.join(cache_dir,
-                                           "campaign_index.json"))
+        assert pc1["dir"] == cache_dir
+        assert os.path.exists(index)
         # the signature index recognizes the bucket next invocation
         _, s2 = campaign.run_campaign([TINY], out_dir=out, n_seeds=1,
-                                      force=True,
-                                      compile_cache=cache_dir)
+                                      force=True)
         assert s2["persistent_cache"]["signature_hits"] == 1
     finally:
         # tmp_path is deleted after the test: don't leave jax's

@@ -160,6 +160,12 @@ def test_multiseed_aggregation():
     assert res["best_score"] == min(per)
     # best_seed is the seed *value* at the argmin position
     assert sb["best_seed"] == sb["list"][int(np.argmin(per))]
+    # each seed's best genome rides along and decodes to the top-level
+    # best design for the best seed
+    genomes = sb["best_genome"]["per_seed"]
+    assert len(genomes) == 3
+    assert (TINY.space().decode(genomes[int(np.argmin(per))])
+            == res["generalized"]["design"])
     # gap statistics present (TINY has specific baselines)
     gp = sb["gap_mean_pct"]["per_seed"]
     assert len(gp) == 3 and np.isfinite(sb["gap_mean_pct"]["mean"])
