@@ -6,9 +6,11 @@ R001  trace purity: no host-sync / impure constructs inside functions
       marked ``@traced_closure`` (core.tracing) — ``.item()``,
       ``float()``/``int()`` on non-literal values, ``np.*`` /
       ``time.*`` / ``random.*`` calls, ``print``, ``global`` mutation,
-      mutable default arguments. Host work inside a traced closure
-      either breaks tracing outright or silently re-executes on every
-      re-trace; hoist it to build time.
+      mutable default arguments, host spans (``tracing.span``). Host
+      work inside a traced closure either breaks tracing outright or
+      silently re-executes on every re-trace; hoist it to build time.
+      A span there would time tracing, not execution: name device
+      work with ``jax.named_scope`` instead.
 R002  cache-key completeness: every ``Scenario`` / ``Budget`` /
       ``Calib`` field must be read by ``runner.cache_key_fields`` or
       listed in ``runner.CACHE_KEY_EXEMPT_FIELDS`` — a new knob can
@@ -28,7 +30,7 @@ from __future__ import annotations
 
 import ast
 import os
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from .findings import Finding
 
@@ -53,6 +55,11 @@ DEPRECATED_STUBS = ("make_scorer", "make_traced_scorer",
 _IMPURE_ROOTS = ("numpy", "time", "random")
 
 _DECORATOR_NAME = "traced_closure"
+
+# core.tracing's host span: a call to it inside a traced closure times
+# tracing, not execution
+_SPAN_NAME = "span"
+_TRACING_MODULE = "tracing"
 
 
 def iter_py_files(repo_root: str,
@@ -95,6 +102,27 @@ def import_aliases(tree: ast.Module) -> Dict[str, str]:
                     continue
                 aliases[a.asname or a.name] = f"{mod}.{a.name}"
     return aliases
+
+
+def span_aliases(tree: ast.Module) -> Set[str]:
+    """Local names bound to ``core.tracing.span`` by a from-import
+    (absolute or relative)."""
+    names: Set[str] = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and (node.module or "") \
+                .split(".")[-1] == _TRACING_MODULE:
+            names |= {a.asname or a.name for a in node.names
+                      if a.name == _SPAN_NAME}
+    return names
+
+
+def _is_span_call(func: ast.expr, spans: Set[str]) -> bool:
+    if isinstance(func, ast.Name):
+        return func.id in spans
+    return (isinstance(func, ast.Attribute) and func.attr == _SPAN_NAME
+            and isinstance(func.value, (ast.Name, ast.Attribute))
+            and (func.value.id if isinstance(func.value, ast.Name)
+                 else func.value.attr) == _TRACING_MODULE)
 
 
 def _resolve_root(node: ast.expr, aliases: Dict[str, str]
@@ -156,7 +184,8 @@ _LITERAL_NODES = (ast.Constant,)
 
 
 def _check_traced_body(path: str, qual: str, fn: ast.AST,
-                       aliases: Dict[str, str]) -> List[Finding]:
+                       aliases: Dict[str, str],
+                       spans: Set[str] = frozenset()) -> List[Finding]:
     out: List[Finding] = []
 
     def bad(node: ast.AST, msg: str) -> None:
@@ -198,6 +227,10 @@ def _check_traced_body(path: str, qual: str, fn: ast.AST,
                     and not node.args and not node.keywords:
                 bad(node, ".item() inside a traced closure "
                           "(host sync; keep the value on device)")
+            if _is_span_call(func, spans):
+                bad(node, "host span inside a traced closure (it times "
+                          "tracing, not execution; name device work "
+                          "with jax.named_scope)")
             if isinstance(func, ast.Name) and func.id == "print":
                 bad(node, "print() inside a traced closure "
                           "(host I/O; use jax.debug.print if needed)")
@@ -226,8 +259,9 @@ def check_traced_purity(repo_root: str) -> List[Finding]:
         if not marked:
             continue
         aliases = import_aliases(tree)
+        spans = span_aliases(tree)
         for qual, fn in marked:
-            findings += _check_traced_body(rel, qual, fn, aliases)
+            findings += _check_traced_body(rel, qual, fn, aliases, spans)
     return findings
 
 

@@ -114,6 +114,10 @@ class ServiceStats:
     latency_p50_s: float
     latency_p90_s: float
     latency_p99_s: float
+    # cumulative, for means over any interval between two snapshots
+    dispatched: int = 0               # requests taken into a batch
+    queue_wait_s: float = 0.0         # sum of their submit -> batch waits
+    batch_s: float = 0.0              # time inside batches (plan to drain)
 
     def asdict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)

@@ -94,6 +94,7 @@ _PARAM_DEFAULTS = {
 
 
 @traced_closure
+@jax.named_scope("cost_model")
 def _resolve(space: SearchSpace, table: jax.Array, genomes: jax.Array,
              ) -> Dict[str, jax.Array]:
     """Gather parameter values for each genome: dict of (P,) arrays.
@@ -109,6 +110,7 @@ def _resolve(space: SearchSpace, table: jax.Array, genomes: jax.Array,
 
 
 @traced_closure
+@jax.named_scope("cost_model")
 def _cost_core(space: SearchSpace, c: HWConstants, p: Dict[str, jax.Array],
                *, M: jax.Array, K: jax.Array, N: jax.Array,
                seg_onehot: jax.Array, stored_weights: jax.Array,

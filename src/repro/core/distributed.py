@@ -63,6 +63,12 @@ def cached_compile(key, builder: Callable, *refs):
     return entry[0]
 
 
+def is_cached(key) -> bool:
+    """Whether ``cached_compile`` holds a kernel under ``key`` (no
+    counter moves)."""
+    return key in _KERNEL_CACHE
+
+
 def kernel_cache_stats() -> dict:
     """Snapshot of the in-process kernel cache counters + current size."""
     return dict(_CACHE_STATS, size=len(_KERNEL_CACHE))

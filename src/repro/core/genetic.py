@@ -186,7 +186,7 @@ def ga_scan(key: jax.Array, init_pop: jax.Array, cards: jax.Array,
     appended final entry equals the unpadded one (see
     experiments/campaign.py's shape bucketing).
     """
-    def body(carry, params):
+    def step(carry, params):
         key, pop, best_g, best_s = carry
         scores = score_fn(pop)
         i = jnp.argmin(scores)
@@ -199,10 +199,13 @@ def ga_scan(key: jax.Array, init_pop: jax.Array, cards: jax.Array,
                                params[0], params[1], params[2], params[3])
         return (key, pop, best_g, best_s), best_s
 
+    body = jax.named_scope("ga_step")(step)
+
+    @jax.named_scope("ga_step")
     def body_masked(carry, xs):
         params, act = xs
         key, pop, best_g, best_s = carry
-        (key2, pop2, best_g2, best_s2), _ = body(
+        (key2, pop2, best_g2, best_s2), _ = step(
             (key, pop, best_g, best_s), params)
         key = jnp.where(act, key2, key)
         pop = jnp.where(act, pop2, pop)

@@ -322,6 +322,7 @@ def make_accuracy_model(space: SearchSpace,
         return snr_db + 10.0 * jnp.log10(cpw)  # multi-cell averaging
 
     @traced_closure
+    @jax.named_scope("noise_fields")
     def _eps_fields(flat_idx):
         # the SAME draws as _noised_weights: eps on the untiled (K, N)
         # weight shape from the design's fold_in key
@@ -365,6 +366,7 @@ def make_accuracy_model(space: SearchSpace,
     batched = jax.vmap(one)
 
     @traced_closure
+    @jax.named_scope("accuracy_model")
     def accuracy(genomes: jax.Array) -> jax.Array:
         genomes = jnp.asarray(genomes)
         flat = _flat_index(genomes, strides)

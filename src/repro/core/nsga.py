@@ -120,6 +120,7 @@ def dominance_matrix_tiled(scores: jax.Array,
 
 
 @traced_closure
+@jax.named_scope("nsga_rank")
 def nondominated_rank(scores: jax.Array,
                       tile: Optional[int] = None) -> jax.Array:
     """(N, D) scores -> (N,) int32 non-domination ranks (0 = front).

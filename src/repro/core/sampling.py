@@ -49,6 +49,7 @@ def random_genomes(key: jax.Array, space: SearchSpace, n: int) -> jax.Array:
 
 
 @traced_closure
+@jax.named_scope("hamming_select")
 def hamming_select(candidates: jax.Array, n_select: int,
                    n_valid: Optional[jax.Array] = None) -> jax.Array:
     """Greedy max-min Hamming-distance subset selection.

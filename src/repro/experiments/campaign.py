@@ -70,9 +70,10 @@ import numpy as np
 from ..core import (MultiMOSearchResult, MultiSearchResult, nonideal,
                     search_kernel)
 from ..core.distributed import (cached_compile, compile_batched_search,
-                                kernel_cache_stats)
+                                is_cached, kernel_cache_stats)
 from ..core.nsga import nsga_search_kernel
 from ..core.scoring import Scorer
+from ..core.tracing import span
 from . import report, runner
 from .scenarios import Scenario
 
@@ -245,20 +246,26 @@ def _build_bucket_kernel(key: Tuple, traced: Scorer, space, mesh,
     generalized reduction differently than the sequential build and
     drift by ULPs — byte-identity to ``run --sequential`` is part of
     the engine's contract.
+
+    The compiled module is named after ``lane``. The name is part of the
+    persistent compilation cache's key, and the named scopes inside are
+    not (the key strips debug info): renaming it when the scopes change
+    keeps a cache from serving an executable whose op metadata lacks
+    the scopes that ``bench/scopes.py`` reads.
     """
     engine, _, p_h, p_e, p_ga, hamming, rram, _ = key
     cards = jnp.asarray(space.cardinalities.astype(np.float32))
     donate = jax.default_backend() != "cpu"
 
     if engine == "nsga":
-        def one(k, schedule, active):
+        def lane(k, schedule, active):
             fe = traced.feasible if rram else None
             return nsga_search_kernel(
                 k, cards, schedule, traced.score_vec, fe, p_h=p_h,
                 p_e=p_e, p_ga=p_ga, hamming_sampling=hamming,
                 active=active)
     elif part == "spec":
-        def one(k, w, schedule, active):
+        def lane(k, w, schedule, active):
             def sc(g):
                 return traced.score_w(g, w)
             fe = None
@@ -269,12 +276,12 @@ def _build_bucket_kernel(key: Tuple, traced: Scorer, space, mesh,
                                  p_e=p_e, p_ga=p_ga,
                                  hamming_sampling=hamming, active=active)
     else:
-        def one(k, schedule, active):
+        def lane(k, schedule, active):
             fe = traced.feasible if rram else None
             return search_kernel(k, cards, schedule, traced.score, fe,
                                  p_h=p_h, p_e=p_e, p_ga=p_ga,
                                  hamming_sampling=hamming, active=active)
-    return compile_batched_search(one, mesh=mesh, donate=donate)
+    return compile_batched_search(lane, mesh=mesh, donate=donate)
 
 
 class _Bucket:
@@ -291,6 +298,8 @@ class _Bucket:
         self.outs = None
         self.spec_outs = None
         self.dispatch_s = 0.0
+        self.wait_s = 0.0
+        self.finalize_s = 0.0
         self.drain_s = 0.0
 
     def add(self, job: CampaignJob) -> None:
@@ -367,13 +376,17 @@ class _Bucket:
                 np.asarray(ws, np.int32), np.stack(scheds),
                 np.stack(actives))
 
-    def _kernel(self, part: str, n_lanes: int) -> object:
-        job = self.jobs[0]
+    def _kernel_key(self, part: str, n_lanes: int) -> Tuple:
+        """(kernel-cache key, mesh) of one lane flavor's kernel."""
         b = lane_tier(n_lanes)
         mesh = runner._search_mesh(b)
+        return (("campaign", self.key, part, b,
+                 mesh.devices.size if mesh is not None else 0), mesh)
+
+    def _kernel(self, key: Tuple, mesh, part: str) -> object:
+        job = self.jobs[0]
         return cached_compile(
-            ("campaign", self.key, part, b,
-             mesh.devices.size if mesh is not None else 0),
+            key,
             lambda: _build_bucket_kernel(self.key, job.traced,
                                          job.setup.space, mesh, part),
             job.traced)
@@ -381,60 +394,86 @@ class _Bucket:
     def dispatch(self) -> None:
         """Trace/compile (cached) + enqueue the device call(s). Returns
         with the result arrays still in flight (jax async dispatch)."""
-        t0 = time.perf_counter()
-        kern = self._kernel("main", self.n_main)
-        self.outs = kern(*[jnp.asarray(a) for a in self._main_arrays()])
+        parts = [("main", self.n_main, self._main_arrays)]
         if self.n_spec:
-            kern = self._kernel("spec", self.n_spec)
-            self.spec_outs = kern(
-                *[jnp.asarray(a) for a in self._spec_arrays()])
-        self.dispatch_s = time.perf_counter() - t0
+            parts.append(("spec", self.n_spec, self._spec_arrays))
+        keys = [self._kernel_key(part, n) for part, n, _ in parts]
+        bucket = self.signature()
+        hit = all(is_cached(key) for key, _ in keys)
+        outs = []
+        with span("campaign.dispatch", bucket=bucket,
+                  kernel_cache="hit" if hit else "miss") as sp:
+            for (part, _, lane_arrays), (key, mesh) in zip(parts, keys):
+                kern = self._kernel(key, mesh, part)
+                with span("campaign.lane_arrays", bucket=bucket,
+                          part=part):
+                    arrays = lane_arrays()
+                outs.append(kern(*[jnp.asarray(a) for a in arrays]))
+        self.outs = outs[0]
+        if self.n_spec:
+            self.spec_outs = outs[1]
+        self.dispatch_s = sp.seconds
 
     def drain(self, out_dir: str, write: bool,
               specific_fanout: bool) -> None:
         """Materialize the bucket's arrays (blocks) and finalize every
         job's result dict + artifacts."""
         t0 = time.perf_counter()
-        outs = [np.asarray(o) for o in self.outs]
-        spec_outs = ([np.asarray(o) for o in self.spec_outs]
-                     if self.spec_outs is not None else None)
+        bucket = self.signature()
+        with span("campaign.device_wait", bucket=bucket) as sp:
+            outs = [np.asarray(o) for o in self.outs]
+            spec_outs = ([np.asarray(o) for o in self.spec_outs]
+                         if self.spec_outs is not None else None)
         self.outs = self.spec_outs = None
-        wall = time.perf_counter() - t0
+        self.wait_s = sp.seconds
         for job, (mo, so) in zip(self.jobs, self.offsets):
-            share = wall * job.n_lanes / max(self.n_lanes, 1)
-            S, T = len(job.seeds), job.sched.shape[0]
-            sl = slice(mo, mo + S)
-            if job.engine == "nsga":
-                pop, scores, ranks, hist = outs
-                res = MultiMOSearchResult(
-                    populations=pop[sl], scores=scores[sl],
-                    ranks=ranks[sl], histories=hist[sl][:, :T + 1],
-                    wall_time_s=share)
-                spec = None
-            else:
-                best_g, best_s, hist, pops, pscores = outs
-                res = MultiSearchResult(
-                    best_genomes=best_g[sl], best_scores=best_s[sl],
-                    histories=np.concatenate(
-                        [hist[sl][:, :T], hist[sl][:, -1:]], axis=1),
-                    populations=pops[sl], scores=pscores[sl],
-                    wall_time_s=share, sampling_time_s=0.0)
-                spec = None
-                if job.wants_spec:
-                    W = job.n_workloads
-                    sp = slice(so, so + S * W)
-                    genomes = spec_outs[0][sp].reshape(S, W, -1)
-                    spec = {
-                        "genomes": genomes,
-                        "best_scores": spec_outs[1][sp].reshape(S, W),
-                        "edap": runner.specific_edap(job.traced,
-                                                     genomes),
-                    }
-            job.result = runner.finalize_result(
-                job.scenario, job.setup, job.traced, res, job.seeds,
-                spec=spec, specific_fanout=specific_fanout,
-                out_dir=out_dir, write=write, t0=job.t0)
+            with span("campaign.finalize", scenario=job.scenario.name,
+                      bucket=bucket) as sp:
+                job.result = self._finalize(job, mo, so, outs, spec_outs,
+                                            out_dir, write,
+                                            specific_fanout)
+            self.finalize_s += sp.seconds
         self.drain_s = time.perf_counter() - t0
+
+    def _finalize(self, job: CampaignJob, mo: int, so: int, outs,
+                  spec_outs, out_dir: str, write: bool,
+                  specific_fanout: bool) -> Dict:
+        """One job's lanes of the drained arrays -> its result dict
+        (+ artifacts) through runner.finalize_result."""
+        share = self.wait_s * job.n_lanes / max(self.n_lanes, 1)
+        S, T = len(job.seeds), job.sched.shape[0]
+        sl = slice(mo, mo + S)
+        spec = None
+        if job.engine == "nsga":
+            pop, scores, ranks, hist = outs
+            res = MultiMOSearchResult(
+                populations=pop[sl], scores=scores[sl],
+                ranks=ranks[sl], histories=hist[sl][:, :T + 1],
+                wall_time_s=share)
+        else:
+            best_g, best_s, hist, pops, pscores = outs
+            res = MultiSearchResult(
+                best_genomes=best_g[sl], best_scores=best_s[sl],
+                histories=np.concatenate(
+                    [hist[sl][:, :T], hist[sl][:, -1:]], axis=1),
+                populations=pops[sl], scores=pscores[sl],
+                wall_time_s=share, sampling_time_s=0.0)
+            if job.wants_spec:
+                W = job.n_workloads
+                ss = slice(so, so + S * W)
+                genomes = spec_outs[0][ss].reshape(S, W, -1)
+                with span("runner.design_metrics",
+                          scenario=job.scenario.name):
+                    edap = runner.specific_edap(job.traced, genomes)
+                spec = {
+                    "genomes": genomes,
+                    "best_scores": spec_outs[1][ss].reshape(S, W),
+                    "edap": edap,
+                }
+        return runner.finalize_result(
+            job.scenario, job.setup, job.traced, res, job.seeds,
+            spec=spec, specific_fanout=specific_fanout,
+            out_dir=out_dir, write=write, t0=job.t0)
 
 
 def bucket_jobs(jobs: Sequence[CampaignJob]
@@ -606,8 +645,10 @@ def run_campaign(scenarios: Sequence[Scenario],
     entries_before = _cache_entries(cache_dir)
     kstats0 = kernel_cache_stats()
 
-    jobs = plan_campaign(scenarios, out_dir=out_dir, force=force,
-                         seed=seed, n_seeds=n_seeds, write=write)
+    with span("campaign.plan", n_scenarios=len(scenarios)) as sp:
+        jobs = plan_campaign(scenarios, out_dir=out_dir, force=force,
+                             seed=seed, n_seeds=n_seeds, write=write)
+    plan_s = sp.seconds
     buckets = bucket_jobs(jobs)
 
     index = _load_index(index_path) if index_path else {}
@@ -650,6 +691,7 @@ def run_campaign(scenarios: Sequence[Scenario],
         "lanes_padded": sum(b.lanes_padded_to - b.n_lanes
                             for b in buckets.values()),
         "wall_time_s": wall,
+        "plan_s": plan_s,
         "scenarios_per_sec": (n_executed / wall if wall > 0
                               else float("inf")),
         "kernel_cache": {
@@ -671,6 +713,8 @@ def run_campaign(scenarios: Sequence[Scenario],
              "lanes_padded_to": b.lanes_padded_to,
              "scenarios": [j.scenario.name for j in b.jobs],
              "dispatch_s": b.dispatch_s,
+             "wait_s": b.wait_s,
+             "finalize_s": b.finalize_s,
              "drain_s": b.drain_s}
             for b in buckets.values()],
     }

@@ -86,7 +86,7 @@ from ..core.objectives import (INFEASIBLE_PENALTY, MultiObjective,
                                per_workload_scores)
 from ..core.scoring import Calib, Scorer, ScorerSpec, build_scorer
 from ..core.pareto import edap_cost_front, hypervolume_2d
-from ..core.tracing import traced_closure
+from ..core.tracing import span, traced_closure
 from ..core.search_space import TECH_NODES_NM, TECH_32NM_INDEX
 from . import report
 from .scenarios import Scenario
@@ -910,6 +910,7 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
             "(almost) the whole space; raise the sampling oversample "
             "or shrink the workloads")
     j_best = int(np.argmin(best_scores))
+    ids = {"scenario": scenario.name}
     if is_mo:
         pareto_block, genomes, scores = _searched_front_block(
             space, traced, res, objective)
@@ -927,6 +928,8 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
         best_genome = best.best_genome
         history = np.asarray(best.history)
         histories = np.asarray(res.histories)
+    with span("runner.design_metrics", **ids):
+        generalized = _design_metrics(space, traced, best_genome, wl_names)
     result: Dict = {
         "scenario": scenario.name,
         "mem": scenario.mem,
@@ -937,8 +940,7 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
         **cache_key_fields(scenario, seed, n_seeds),
         "workloads": list(wl_names),
         "best_score": float(best_scores[j_best]),
-        "generalized": _design_metrics(space, traced, best_genome,
-                                       wl_names),
+        "generalized": generalized,
         # best seed's best-so-far trajectory (first objective for MO) +
         # every seed's, for the Fig. 4 convergence bands in summary.md
         "history": np.asarray(history).tolist(),
@@ -992,8 +994,9 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
                                                seeds)
 
         # per-seed generalized EDAPs -> per-seed gap (one device call)
-        m_gen = traced.metrics(jnp.asarray(res.best_genomes))
-        gen_edap = np.asarray(per_workload_scores(m_gen, "edap"))
+        with span("runner.design_metrics", **ids):
+            m_gen = traced.metrics(jnp.asarray(res.best_genomes))
+            gen_edap = np.asarray(per_workload_scores(m_gen, "edap"))
         with np.errstate(divide="ignore", invalid="ignore"):
             gap_pct = 100.0 * (gen_edap / spec["edap"] - 1.0)
         gap_means = np.mean(gap_pct, axis=1)
@@ -1008,29 +1011,31 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
 
         if write:
             os.makedirs(sdir, exist_ok=True)
-            m_spec = traced.metrics(jnp.asarray(
-                spec["genomes"][j_best]))
-            for i, n in enumerate(names):
-                sub = {
-                    "design": space.decode(spec["genomes"][j_best, i]),
-                    "objective_score": float(
-                        spec["best_scores"][j_best, i]),
-                    "area_mm2": float(np.asarray(m_spec.area)[i]),
-                    "feasible": bool(
-                        np.asarray(m_spec.feasible_w)[i, i]),
-                    "per_workload": {
-                        n: {"energy_mJ":
-                            float(np.asarray(m_spec.energy)[i, i]) * 1e3,
-                            "latency_ms":
-                            float(np.asarray(m_spec.latency)[i, i]) * 1e3,
-                            "edap": float(spec["edap"][j_best, i])}},
-                    "best_score": float(spec["best_scores"][j_best, i]),
-                    "seed": seed,
-                }
-                with open(os.path.join(sdir, f"specific_{n}.json"),
-                          "w") as f:
-                    json.dump(sub, f, indent=1, sort_keys=True,
-                              default=float)
+            with span("runner.design_metrics", **ids):
+                m_spec = traced.metrics(jnp.asarray(
+                    spec["genomes"][j_best]))
+            with span("runner.write_artifacts", **ids):
+                energy = np.asarray(m_spec.energy)
+                latency = np.asarray(m_spec.latency)
+                for i, n in enumerate(names):
+                    sub = {
+                        "design": space.decode(spec["genomes"][j_best, i]),
+                        "objective_score": float(
+                            spec["best_scores"][j_best, i]),
+                        "area_mm2": float(np.asarray(m_spec.area)[i]),
+                        "feasible": bool(
+                            np.asarray(m_spec.feasible_w)[i, i]),
+                        "per_workload": {
+                            n: {"energy_mJ": float(energy[i, i]) * 1e3,
+                                "latency_ms": float(latency[i, i]) * 1e3,
+                                "edap": float(spec["edap"][j_best, i])}},
+                        "best_score": float(spec["best_scores"][j_best, i]),
+                        "seed": seed,
+                    }
+                    with open(os.path.join(sdir, f"specific_{n}.json"),
+                              "w") as f:
+                        json.dump(sub, f, indent=1, sort_keys=True,
+                                  default=float)
 
     result["seeds"] = report.aggregate_seeds(seeds, best_scores,
                                              gap_means)
@@ -1041,5 +1046,6 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
             "per_seed": np.asarray(res.best_genomes).tolist()}
     result["wall_time_s"] = time.perf_counter() - t0
     if write:
-        report.write_artifacts(result, sdir)
+        with span("runner.write_artifacts", **ids):
+            report.write_artifacts(result, sdir)
     return result

@@ -185,5 +185,6 @@ def imc_fused_gemm(x_q: jax.Array, w: jax.Array, eps_pos: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, B, N), jnp.float32),
         interpret=interpret,
+        name="imc_fused",
     )(rows_idx.astype(jnp.int32), row_table.astype(jnp.int32),
       x_q.astype(jnp.int32).T, w.astype(jnp.float32), eps_pos, eps_neg)

@@ -57,6 +57,9 @@ def render_stats(stats) -> str:
         f"(hit rate {stats.kernel_cache_hit_rate:.2f})",
         f"  latency           p50 {stats.latency_p50_s:.2f}s   "
         f"p90 {stats.latency_p90_s:.2f}s   p99 {stats.latency_p99_s:.2f}s",
+        f"  mean time         queue wait "
+        f"{stats.queue_wait_s / max(stats.dispatched, 1):.2f}s   "
+        f"batch {stats.batch_s / max(stats.batches, 1):.2f}s",
     ])
 
 

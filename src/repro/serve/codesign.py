@@ -54,6 +54,7 @@ import numpy as np
 from ..api import (ProgressEvent, SearchRequest, SearchResponse,
                    ServiceStats, resolve_request)
 from ..core.distributed import kernel_cache_stats
+from ..core.tracing import span
 from ..experiments import campaign, runner
 from ..experiments.scenarios import Scenario
 
@@ -128,7 +129,11 @@ class CodesignService:
         self._counts = {k: 0 for k in (
             "submitted", "completed", "cancelled", "expired", "failed",
             "result_cache_hits", "batches", "buckets",
-            "degraded_buckets", "lanes_total", "lanes_padded")}
+            "degraded_buckets", "lanes_total", "lanes_padded",
+            "dispatched")}
+        # cumulative seconds: submit -> batch, and inside _execute
+        self._queue_wait_s = 0.0
+        self._batch_s = 0.0
 
     # -- public API ---------------------------------------------------------
 
@@ -154,18 +159,21 @@ class CodesignService:
                ) -> str:
         """Enqueue a request; returns its id immediately. A bare
         registry name or Scenario wraps into a default SearchRequest."""
-        if isinstance(request, (str, Scenario)):
-            request = SearchRequest(scenario=request)
-        scenario = resolve_request(request)
-        with self._cond:
-            if self._closed:
-                raise RuntimeError("service is closed")
-            rid = f"req-{next(self._ids):04d}"
-            rec = _Record(rid, request, scenario, time.monotonic())
-            self._records[rid] = rec
-            self._queue.append(rec)
-            self._counts["submitted"] += 1
-            self._cond.notify_all()
+        # stamped before resolution, so latency and queue wait cover it
+        now = time.monotonic()
+        rid = f"req-{next(self._ids):04d}"
+        with span("service.submit", request=rid):
+            if isinstance(request, (str, Scenario)):
+                request = SearchRequest(scenario=request)
+            scenario = resolve_request(request)
+            with self._cond:
+                if self._closed:
+                    raise RuntimeError("service is closed")
+                rec = _Record(rid, request, scenario, now)
+                self._records[rid] = rec
+                self._queue.append(rec)
+                self._counts["submitted"] += 1
+                self._cond.notify_all()
         if self._autostart:
             self.start()
         return rid
@@ -214,8 +222,9 @@ class CodesignService:
                               if r.status == _QUEUED)
             inflight = sum(1 for r in self._records.values()
                            if r.status == _DISPATCHED)
-            span = self._last_done_t - self._t_start
+            active = self._last_done_t - self._t_start
             uptime = time.monotonic() - self._t_start
+            queue_wait, batch_s = self._queue_wait_s, self._batch_s
         k = kernel_cache_stats()
         kh = k["hits"] - self._kstats0["hits"]
         km = k["misses"] - self._kstats0["misses"]
@@ -237,12 +246,13 @@ class CodesignService:
             lanes_padded=c["lanes_padded"],
             bucket_occupancy=(c["lanes_total"] / lanes if lanes
                               else 1.0),
-            requests_per_sec=(c["completed"] / span if span > 0
+            requests_per_sec=(c["completed"] / active if active > 0
                               and c["completed"] else 0.0),
             kernel_cache_hits=kh, kernel_cache_misses=km,
             kernel_cache_hit_rate=(kh / (kh + km) if kh + km else 0.0),
             latency_p50_s=pct(50), latency_p90_s=pct(90),
-            latency_p99_s=pct(99))
+            latency_p99_s=pct(99), dispatched=c["dispatched"],
+            queue_wait_s=queue_wait, batch_s=batch_s)
 
     def close(self, drain: bool = True) -> None:
         """Stop the service. ``drain=True`` (default) finishes every
@@ -270,12 +280,18 @@ class CodesignService:
                 if self._stop and not self._queue:
                     return
             if self.window_s > 0:
-                time.sleep(self.window_s)  # micro-batch accumulation
+                with span("service.collect_wait"):
+                    time.sleep(self.window_s)  # micro-batch accumulation
             batch = self._collect()
             if batch:
                 with self._cond:
                     self._counts["batches"] += 1
-                self._execute(batch)
+                # space-joined: the profiler splits span ids on commas
+                with span("service.batch", requests=" ".join(
+                        r.rid for r in batch)) as sp:
+                    self._execute(batch)
+                with self._cond:
+                    self._batch_s += sp.seconds
 
     def _collect(self) -> List[_Record]:
         """Close the window: pop up to max_batch queued records,
@@ -295,6 +311,8 @@ class CodesignService:
                     continue
                 rec.status = _DISPATCHED
                 rec.dispatch_t = now
+                self._counts["dispatched"] += 1
+                self._queue_wait_s += now - rec.submitted_t
                 batch.append(rec)
         return batch
 
@@ -302,9 +320,10 @@ class CodesignService:
         """One batch end-to-end: plan -> cached -> buckets (async,
         degradable) -> fallbacks. Every record terminates."""
         try:
-            jobs = campaign.plan_campaign(
-                [r.scenario for r in records], out_dir=self.out_dir,
-                force=self.force, write=self.write)
+            with span("campaign.plan", n_scenarios=len(records)):
+                jobs = campaign.plan_campaign(
+                    [r.scenario for r in records], out_dir=self.out_dir,
+                    force=self.force, write=self.write)
         except Exception:
             err = traceback.format_exc(limit=8)
             for rec in records:

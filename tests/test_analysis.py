@@ -88,6 +88,33 @@ def test_r001_construct_catalog(tmp_path, body, needle):
     assert len(findings) == 1 and needle in findings[0].message
 
 
+@pytest.mark.parametrize("imports,call", [
+    ("from .tracing import span", "span"),
+    ("from repro.core.tracing import span as sp", "sp"),
+    ("from . import tracing", "tracing.span"),
+])
+def test_r001_flags_host_span_in_traced_closure(tmp_path, imports, call):
+    src = f"""
+        {imports}
+        from .tracing import traced_closure
+
+        @traced_closure
+        def score(genomes):
+            with {call}("score"):  # the one violation
+                return genomes
+
+        def host_step(x):
+            with {call}("host_step"):  # unmarked: a span belongs here
+                return x
+    """
+    root = mini_repo(tmp_path, {"src/repro/core/spans.py": src})
+    findings = check_traced_purity(root)
+    assert len(findings) == 1
+    f = findings[0]
+    assert (f.rule, f.symbol) == ("R001", "score")
+    assert "host span" in f.message
+
+
 def test_r001_mutable_default_but_not_frozen_dataclass(tmp_path):
     src = """
         from .tracing import traced_closure
