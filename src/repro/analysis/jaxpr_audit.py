@@ -9,7 +9,7 @@ J001  purity: the lowered jaxpr contains ZERO callback primitives
       ``*callback*``) — the whole search is device-resident, nothing
       punches out to host mid-computation.
 J002  recompilation: kernels whose content signature is identical
-      (campaign.scorer_key + engine + population/schedule shape) must
+      (runner.scorer_key + engine + population/schedule shape) must
       lower to ONE jaxpr — a hash split inside a signature group means
       the compile cache misses for work that should share a kernel.
 J003  bloat: per-kernel total primitive counts are diffed against the
@@ -100,7 +100,7 @@ def _smoke(scenario):
 def _group_key(scenario, engine: str, shape: Tuple) -> str:
     """J002 signature: scenarios sharing this string MUST lower to one
     jaxpr (it is the campaign engine's bucketing contract)."""
-    from ..experiments.campaign import scorer_key
+    from ..experiments.runner import scorer_key
     return repr((scorer_key(scenario), engine, shape))
 
 

@@ -118,6 +118,8 @@ class ServiceStats:
     dispatched: int = 0               # requests taken into a batch
     queue_wait_s: float = 0.0         # sum of their submit -> batch waits
     batch_s: float = 0.0              # time inside batches (plan to drain)
+    design_calls: int = 0             # compiled design-table calls of
+                                      # finalize: one per finalized job
 
     def asdict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)

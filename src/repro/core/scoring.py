@@ -100,6 +100,12 @@ class Scorer:
     sorts inside the scan; ``score`` then restricts to the first
     component.
 
+    ``design`` gathers, in one trace, every number a finished search
+    reports of its designs: the CostMetrics fields, the per-workload
+    EDAP, the first objective component's aggregated EDAP (the post-hoc
+    front's axis), ``score`` and, with an accuracy model, ``accuracy``
+    (experiments.runner.design_table compiles it per content key).
+
     Host-facing: ``score_host`` is jitted and, on multi-device
     runtimes, population-sharded over the mesh 'data' axis (with
     transparent padding to the device count); ``evaluator`` is the
@@ -115,6 +121,7 @@ class Scorer:
     metrics: Callable               # (P, n) -> CostMetrics
     accuracy: Optional[Callable] = None   # (P, n) -> (P, W)
     score_vec: Optional[Callable] = None  # (P, n) -> (P, D), MO only
+    design: Optional[Callable] = None     # (R, n) -> {name: (R, ...)}
     score_host: Optional[Callable] = None
     evaluator: Optional[Callable] = None
     backend: str = "jnp"
@@ -219,6 +226,21 @@ def build_scorer(space: SearchSpace, spec: ScorerSpec, *,
             bad = bad | (acc[:, w] < first.min_accuracy)
         return jnp.where(bad, INFEASIBLE_PENALTY, s)
 
+    edap_first = Objective("edap", first.aggregation,
+                           first.area_constraint)
+
+    @traced_closure
+    def design(genomes):
+        m = metrics(genomes)
+        table = {"energy": m.energy, "latency": m.latency,
+                 "area": m.area, "feasible": m.feasible,
+                 "feasible_w": m.feasible_w, "cost": m.cost,
+                 "edap": per_workload_scores(m, "edap"),
+                 "edap_agg": edap_first(m), "score": score(genomes)}
+        if acc_fn is not None:
+            table["accuracy"] = acc_fn(genomes)
+        return table
+
     evaluator = jax.jit(metrics)
     n_dev = jax.device_count()
     if mesh is None and n_dev > 1:
@@ -241,6 +263,6 @@ def build_scorer(space: SearchSpace, spec: ScorerSpec, *,
 
     return Scorer(score=score, feasible=feasible, score_w=score_w,
                   feasible_w=feasible_w, metrics=metrics,
-                  accuracy=acc_fn, score_vec=score_vec,
+                  accuracy=acc_fn, score_vec=score_vec, design=design,
                   score_host=score_host, evaluator=evaluator,
                   backend=backend, calib=calib, budget=budget)

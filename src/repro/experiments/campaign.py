@@ -67,8 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import (MultiMOSearchResult, MultiSearchResult, nonideal,
-                    search_kernel)
+from ..core import MultiMOSearchResult, MultiSearchResult, search_kernel
 from ..core.distributed import (cached_compile, compile_batched_search,
                                 is_cached, kernel_cache_stats)
 from ..core.nsga import nsga_search_kernel
@@ -107,19 +106,6 @@ def lane_tier(b: int) -> int:
     return -(-_tier(b, LANE_TIERS, 128) // n_dev) * n_dev
 
 
-def scorer_key(scenario: Scenario) -> Tuple:
-    """Content key of a scenario's Scorer: two scenarios with equal
-    keys build arithmetically identical scorers (same space, workload
-    set, objective, calibration fidelity and resolved backend), so the
-    campaign builds one Scorer — and one jitted evaluator — for e.g. a
-    scenario and its ``_plain`` / ``_random`` registry variants."""
-    return (scenario.mem, scenario.reduced_space, scenario.tech_variable,
-            scenario.workload_source, tuple(scenario.workloads),
-            scenario.seq, scenario.objective, scenario.min_accuracy,
-            scenario.n_calib, scenario.calib_k,
-            nonideal.resolve_backend(scenario.backend))
-
-
 @dataclasses.dataclass
 class CampaignJob:
     """One scenario run inside a campaign."""
@@ -154,7 +140,7 @@ class CampaignJob:
 
     def bucket_key(self) -> Tuple:
         sc = self.scenario
-        return (self.engine, scorer_key(sc), self.p_h, self.p_e,
+        return (self.engine, runner.scorer_key(sc), self.p_h, self.p_e,
                 sc.budget.p_ga, self.hamming, sc.mem == "rram",
                 gen_tier(self.sched.shape[0]))
 
@@ -206,7 +192,7 @@ def plan_campaign(scenarios: Sequence[Scenario],
             job.kind = "fallback"
             jobs.append(job)
             continue
-        key = scorer_key(sc)
+        key = runner.scorer_key(sc)
         if key not in scorers:
             st = runner.setup_scenario(sc)
             scorers[key] = (st, runner.build_scenario_scorer(sc, st))
@@ -301,6 +287,7 @@ class _Bucket:
         self.wait_s = 0.0
         self.finalize_s = 0.0
         self.drain_s = 0.0
+        self.design_calls = 0   # design-table calls of its finalizes
 
     def add(self, job: CampaignJob) -> None:
         self.offsets.append((self.n_main, self.n_spec))
@@ -427,12 +414,14 @@ class _Bucket:
         self.outs = self.spec_outs = None
         self.wait_s = sp.seconds
         for job, (mo, so) in zip(self.jobs, self.offsets):
+            calls = runner.design_calls()
             with span("campaign.finalize", scenario=job.scenario.name,
                       bucket=bucket) as sp:
                 job.result = self._finalize(job, mo, so, outs, spec_outs,
                                             out_dir, write,
                                             specific_fanout)
             self.finalize_s += sp.seconds
+            self.design_calls += runner.design_calls() - calls
         self.drain_s = time.perf_counter() - t0
 
     def _finalize(self, job: CampaignJob, mo: int, so: int, outs,
@@ -461,15 +450,8 @@ class _Bucket:
             if job.wants_spec:
                 W = job.n_workloads
                 ss = slice(so, so + S * W)
-                genomes = spec_outs[0][ss].reshape(S, W, -1)
-                with span("runner.design_metrics",
-                          scenario=job.scenario.name):
-                    edap = runner.specific_edap(job.traced, genomes)
-                spec = {
-                    "genomes": genomes,
-                    "best_scores": spec_outs[1][ss].reshape(S, W),
-                    "edap": edap,
-                }
+                spec = {"genomes": spec_outs[0][ss].reshape(S, W, -1),
+                        "best_scores": spec_outs[1][ss].reshape(S, W)}
         return runner.finalize_result(
             job.scenario, job.setup, job.traced, res, job.seeds,
             spec=spec, specific_fanout=specific_fanout,
@@ -502,6 +484,7 @@ def _run_bucket_sequential(bucket: _Bucket, out_dir: str, write: bool,
     for job in bucket.jobs:
         if job.result is not None:
             continue
+        calls = runner.design_calls()
         try:
             job.result = runner.run_scenario(
                 job.scenario, out_dir=out_dir, force=True,
@@ -510,6 +493,7 @@ def _run_bucket_sequential(bucket: _Bucket, out_dir: str, write: bool,
         except Exception:
             job.error = (f"bucket degraded ({cause}); sequential retry "
                          f"failed:\n{traceback.format_exc(limit=8)}")
+        bucket.design_calls += runner.design_calls() - calls
 
 
 def execute_buckets(buckets: Sequence[_Bucket],
@@ -715,7 +699,8 @@ def run_campaign(scenarios: Sequence[Scenario],
              "dispatch_s": b.dispatch_s,
              "wait_s": b.wait_s,
              "finalize_s": b.finalize_s,
-             "drain_s": b.drain_s}
+             "drain_s": b.drain_s,
+             "design_calls": b.design_calls}
             for b in buckets.values()],
     }
     if write:

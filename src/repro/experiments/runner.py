@@ -64,6 +64,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -80,7 +81,7 @@ from ..core import (FOUR_PHASES, MultiMOSearchResult, MultiSearchResult,
                     random_search, search_kernel)
 from ..core.cost_model import HWConstants, evaluate_population
 from ..core.workloads import WorkloadFamily, make_workload_builder
-from ..core.distributed import compile_batched_search
+from ..core.distributed import cached_compile, compile_batched_search
 from ..core.objectives import (INFEASIBLE_PENALTY, MultiObjective,
                                Objective, aggregate_scores,
                                per_workload_scores)
@@ -504,9 +505,10 @@ def run_specific_fanout(scenario: Scenario, space: SearchSpace,
     """The (S seeds x W workloads) specific-baseline searches as ONE
     batched device call — replaces the sequential per-workload loop.
 
-    Returns arrays keyed 'genomes' (S, W, n), 'best_scores' (S, W) and
-    'edap' (S, W): the specific design's EDAP on its own workload.
-    Seeds per search match the sequential path: seed + 1000 + i.
+    Returns arrays keyed 'genomes' (S, W, n) and 'best_scores' (S, W);
+    finalize_result reads each specific design's EDAP on its own
+    workload from its design table. Seeds per search match the
+    sequential path: seed + 1000 + i.
     """
     S, W = len(seeds), n_workloads
     sched, p_h, p_e, hamming = _specific_budget(scenario)
@@ -538,21 +540,8 @@ def run_specific_fanout(scenario: Scenario, space: SearchSpace,
     scheds = jnp.broadcast_to(schedule, (S * W,) + schedule.shape)
     actives = jnp.ones((S * W, schedule.shape[0]), bool)
     best_g, best_s, _, _, _ = fn(keys, ws, scheds, actives)
-    genomes = np.asarray(best_g).reshape(S, W, -1)
-    best_scores = np.asarray(best_s).reshape(S, W)
-    return {"genomes": genomes, "best_scores": best_scores,
-            "edap": specific_edap(traced, genomes)}
-
-
-def specific_edap(traced: TracedScorer, genomes: np.ndarray) -> np.ndarray:
-    """Each specific design's EDAP on its own workload: (S, W, n)
-    genomes -> (S, W). EDAP is the gap metric regardless of the search
-    objective kind; shared by the fan-out above and the campaign
-    engine's lane reassembly."""
-    S, W = genomes.shape[:2]
-    m = traced.metrics(jnp.asarray(genomes.reshape(S * W, -1)))
-    edap_all = np.asarray(per_workload_scores(m, "edap")).reshape(S, W, W)
-    return edap_all[:, np.arange(W), np.arange(W)]
+    return {"genomes": np.asarray(best_g).reshape(S, W, -1),
+            "best_scores": np.asarray(best_s).reshape(S, W)}
 
 
 def _single_workload(scenario: Scenario, wl_name: str) -> Scenario:
@@ -576,7 +565,8 @@ def run_specific_sequential(scenario: Scenario, space: SearchSpace,
     i.e. without a capacity filter (SRAM). For RRAM the two paths draw
     their initial pools differently (device-masked oversampling vs the
     host rejection loop), so per-seed trajectories legitimately
-    differ."""
+    differ. Its 'edap' evaluates each design on its own single-workload
+    pack; finalize_result reports the design table's instead."""
     S, W = len(seeds), len(workloads)
     genomes, best_scores, edap = None, np.zeros((S, W)), np.zeros((S, W))
     for i, w in enumerate(workloads):
@@ -605,25 +595,70 @@ def run_specific_sequential(scenario: Scenario, space: SearchSpace,
     return {"genomes": genomes, "best_scores": best_scores, "edap": edap}
 
 
-def _design_metrics(space: SearchSpace, traced: TracedScorer,
-                    genome: np.ndarray, names) -> Dict:
-    g = jnp.asarray(np.asarray(genome)[None])
-    m = traced.metrics(g)
-    edap = np.asarray(per_workload_scores(m, "edap"))[0]
-    acc = (np.asarray(traced.accuracy(g))[0]
-           if traced.accuracy is not None else None)
+def _design_rows(n: int) -> int:
+    """Row tier of a design table: powers of two from 8, so the row
+    counts finalize sees (seeds + specific designs + post-hoc front
+    candidates) compile a bounded set of shapes."""
+    return max(8, 1 << (n - 1).bit_length())
+
+
+# design-table calls made on each thread (see design_calls)
+_DESIGN_CALLS = threading.local()
+
+
+def design_calls() -> int:
+    """Design-table calls this thread has made: callers difference two
+    readings to count a finalize's calls (one per finalized job)."""
+    return getattr(_DESIGN_CALLS, "n", 0)
+
+
+def design_table(scenario: Scenario, traced: TracedScorer,
+                 genomes: np.ndarray) -> Dict[str, np.ndarray]:
+    """``traced.design`` of (R, n) genomes as ONE compiled device call,
+    read back to the host once: {name: (R, ...) NumPy array}.
+
+    The jitted table is registered with ``cached_compile`` under the
+    scenario's content key (``scorer_key``) and the row tier, so every
+    campaign, service batch and sequential run of an equal scorer in
+    the process reuses one executable per tier (a jit of the
+    per-campaign Scorer would retrace every campaign). Rows pad to the
+    tier with copies of row 0 and are sliced off: the cost and
+    accuracy models are elementwise over rows, so padding changes no
+    real row. ``traced`` must be the scenario's own scorer
+    (``build_scenario_scorer``)."""
+    genomes = np.asarray(genomes)
+    n = genomes.shape[0]
+    tier = _design_rows(n)
+    fn = cached_compile(("design_table", scorer_key(scenario), tier),
+                        lambda: jax.jit(traced.design), traced)
+    padded = np.concatenate(
+        [genomes, np.repeat(genomes[:1], tier - n, axis=0)])
+    table = jax.device_get(fn(padded))
+    _DESIGN_CALLS.n = design_calls() + 1
+    return {k: v[:n] for k, v in table.items()}
+
+
+def _rows(table: Dict[str, np.ndarray], start: int,
+          stop: Optional[int]) -> Dict[str, np.ndarray]:
+    return {k: v[start:stop] for k, v in table.items()}
+
+
+def _design_metrics(space: SearchSpace, table: Dict[str, np.ndarray],
+                    j: int, genome: np.ndarray, names) -> Dict:
+    """Row ``j`` of a design table -> the result's design block."""
+    acc = table.get("accuracy")
     per = {}
     for i, n in enumerate(names):
-        per[n] = {"energy_mJ": float(m.energy[0, i]) * 1e3,
-                  "latency_ms": float(m.latency[0, i]) * 1e3,
-                  "edap": float(edap[i])}
+        per[n] = {"energy_mJ": float(table["energy"][j, i]) * 1e3,
+                  "latency_ms": float(table["latency"][j, i]) * 1e3,
+                  "edap": float(table["edap"][j, i])}
         if acc is not None:
-            per[n]["accuracy"] = float(acc[i])
+            per[n]["accuracy"] = float(acc[j, i])
     return {
         "design": space.decode(genome),
-        "objective_score": float(traced.score(g)[0]),
-        "area_mm2": float(m.area[0]),
-        "feasible": bool(m.feasible[0]),
+        "objective_score": float(table["score"][j]),
+        "area_mm2": float(table["area"][j]),
+        "feasible": bool(table["feasible"][j]),
         "per_workload": per,
     }
 
@@ -646,21 +681,16 @@ def _tech_nm_of(space: SearchSpace, genome: np.ndarray) -> float:
     return float(TECH_NODES_NM[ti])
 
 
-def _pareto_block(space: SearchSpace, traced: TracedScorer,
-                  res: MultiSearchResult, objective: Objective) -> Dict:
+def _pareto_block(space: SearchSpace, cand: np.ndarray,
+                  table: Dict[str, np.ndarray]) -> Dict:
     """EDAP × fabrication-cost Pareto front over the candidate designs
-    the search visited (final populations of every seed) — the Fig. 9
-    construction, *post hoc*: single-objective pressure chose the
-    candidates, the front is filtered afterwards. EDAP keeps the
-    objective's aggregation but drops the cost factor, so the two
-    front axes are the paper's."""
-    cand = np.unique(
-        np.asarray(res.populations).reshape(-1, space.n_params), axis=0)
-    m = traced.metrics(jnp.asarray(cand))
-    edap = np.asarray(
-        Objective("edap", objective.aggregation,
-                  objective.area_constraint)(m))
-    cost = np.asarray(m.cost)
+    the search visited (the distinct designs of every seed's final
+    population; ``table`` holds their design-table rows) — the Fig. 9 construction, *post hoc*:
+    single-objective pressure chose the candidates, the front is
+    filtered afterwards. EDAP keeps the objective's aggregation but
+    drops the cost factor (``edap_agg``), so the two front axes are
+    the paper's."""
+    edap, cost = table["edap_agg"], table["cost"]
     ok = np.isfinite(edap) & (edap < INFEASIBLE_PENALTY)
     cand, edap, cost = cand[ok], edap[ok], cost[ok]
     idx, e_f, c_f = edap_cost_front(edap, cost)
@@ -793,6 +823,20 @@ def setup_scenario(scenario: Scenario) -> ScenarioSetup:
                          objective=objective)
 
 
+def scorer_key(scenario: Scenario) -> Tuple:
+    """Content key of a scenario's Scorer: two scenarios with equal
+    keys build arithmetically identical scorers (same space, workload
+    set, objective, calibration fidelity and resolved backend), so the
+    campaign builds one Scorer — and one jitted evaluator — for e.g. a
+    scenario and its ``_plain`` / ``_random`` registry variants, and
+    every equal scorer shares one compiled design table."""
+    return (scenario.mem, scenario.reduced_space, scenario.tech_variable,
+            scenario.workload_source, tuple(scenario.workloads),
+            scenario.seq, scenario.objective, scenario.min_accuracy,
+            scenario.n_calib, scenario.calib_k,
+            nonideal.resolve_backend(scenario.backend))
+
+
 def build_scenario_scorer(scenario: Scenario,
                           st: ScenarioSetup) -> Scorer:
     """The scenario's Scorer, exactly as the sequential path builds it
@@ -890,8 +934,12 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
     fields).
 
     ``spec`` optionally injects precomputed specific-baseline arrays
-    ('genomes'/'best_scores'/'edap', the run_specific_fanout schema);
-    when None the fan-out (or the sequential fallback) runs here.
+    ('genomes'/'best_scores', the run_specific_fanout schema); when
+    None the fan-out (or the sequential fallback) runs here.
+
+    Every design the result reports — each seed's best (the MO
+    representative), the specific designs, the post-hoc front's
+    candidates — is evaluated by ONE ``design_table`` call.
     """
     if t0 is None:
         t0 = time.perf_counter()
@@ -921,15 +969,48 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
                 f"scenario {scenario.name!r}: the searched front holds "
                 "no feasible design")
         best_genome = genomes[int(np.argmin(scores[:, 0]))]
+        main, j_row = best_genome[None], 0
         history = res.histories[j_best, :, 0]
         histories = res.histories[:, :, 0]
     else:
         best = res.seed_result(j_best)
         best_genome = best.best_genome
+        main, j_row = np.asarray(res.best_genomes), j_best
         history = np.asarray(best.history)
         histories = np.asarray(res.histories)
+
+    # Workload-specific baselines: the same algorithm/budget aimed at
+    # each workload alone — the normalization the paper's gap claims
+    # (and Fig. 5) are built on. All (seed x workload) searches run as
+    # one batched device call for every GA algorithm and objective
+    # kind; only the random-search baseline stays sequential.
+    W = len(workloads)
+    wants_spec = scenario.specific_baselines and W > 1 and not is_mo
+    if wants_spec and spec is None:
+        if specific_fanout and scenario.algorithm != "random":
+            spec = run_specific_fanout(scenario, space, traced, seeds, W)
+        else:
+            spec = run_specific_sequential(scenario, space, objective,
+                                           workloads, seeds)
+    # §IV-I: the EDAP × fabrication-cost trade-off the search explored
+    # (Fig. 9's front), from the final populations
+    cand = None
+    if not is_mo and objective.kind == "edap_cost":
+        cand = np.unique(np.asarray(res.populations).reshape(
+            -1, space.n_params), axis=0)
+
+    # every design the result reports, evaluated in one device call:
+    # [main rows | S*W specific designs | post-hoc front candidates]
+    n_main = main.shape[0]
+    n_spec = n_seeds * W if wants_spec else 0
+    blocks = [main]
+    if wants_spec:
+        blocks.append(spec["genomes"].reshape(n_spec, -1))
+    if cand is not None:
+        blocks.append(cand)
     with span("runner.design_metrics", **ids):
-        generalized = _design_metrics(space, traced, best_genome, wl_names)
+        table = design_table(scenario, traced, np.concatenate(blocks))
+
     result: Dict = {
         "scenario": scenario.name,
         "mem": scenario.mem,
@@ -940,7 +1021,8 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
         **cache_key_fields(scenario, seed, n_seeds),
         "workloads": list(wl_names),
         "best_score": float(best_scores[j_best]),
-        "generalized": generalized,
+        "generalized": _design_metrics(space, table, j_row, best_genome,
+                                       wl_names),
         # best seed's best-so-far trajectory (first objective for MO) +
         # every seed's, for the Fig. 4 convergence bands in summary.md
         "history": np.asarray(history).tolist(),
@@ -970,65 +1052,47 @@ def finalize_result(scenario: Scenario, st: ScenarioSetup,
         # the direct-searched front (Fig. 9 by NSGA-II)
         result["pareto"] = pareto_block
         result["history_mo"] = res.histories[j_best].tolist()
-    elif objective.kind == "edap_cost":
-        # §IV-I: the EDAP × fabrication-cost trade-off the search
-        # explored (Fig. 9's front), from the final populations
-        result["pareto"] = _pareto_block(space, traced, res, objective)
+    elif cand is not None:
+        result["pareto"] = _pareto_block(
+            space, cand, _rows(table, n_main + n_spec, None))
 
-    # Workload-specific baselines: the same algorithm/budget aimed at
-    # each workload alone — the normalization the paper's gap claims
-    # (and Fig. 5) are built on. All (seed x workload) searches run as
-    # one batched device call for every GA algorithm and objective
-    # kind; only the random-search baseline stays sequential.
     gap_means = None
-    if scenario.specific_baselines and len(workloads) > 1 and not is_mo:
-        if spec is None:
-            use_fanout = (specific_fanout
-                          and scenario.algorithm != "random")
-            if use_fanout:
-                spec = run_specific_fanout(scenario, space, traced,
-                                           seeds, len(workloads))
-            else:
-                spec = run_specific_sequential(scenario, space,
-                                               objective, workloads,
-                                               seeds)
-
-        # per-seed generalized EDAPs -> per-seed gap (one device call)
-        with span("runner.design_metrics", **ids):
-            m_gen = traced.metrics(jnp.asarray(res.best_genomes))
-            gen_edap = np.asarray(per_workload_scores(m_gen, "edap"))
+    if wants_spec:
+        # each specific design's EDAP on its own workload (the gap
+        # metric whatever the objective kind) -> per-seed gap
+        ar = np.arange(W)
+        spec_edap = table["edap"][n_main:n_main + n_spec].reshape(
+            n_seeds, W, W)[:, ar, ar]
         with np.errstate(divide="ignore", invalid="ignore"):
-            gap_pct = 100.0 * (gen_edap / spec["edap"] - 1.0)
+            gap_pct = 100.0 * (table["edap"][:n_main] / spec_edap - 1.0)
         gap_means = np.mean(gap_pct, axis=1)
 
         names = [w.name for w in workloads]
         result["specific"] = {
             n: {"design": space.decode(spec["genomes"][j_best, i]),
-                "edap": float(spec["edap"][j_best, i])}
+                "edap": float(spec_edap[j_best, i])}
             for i, n in enumerate(names)
         }
         result["gap"] = report.compute_gap(result)
 
         if write:
             os.makedirs(sdir, exist_ok=True)
-            with span("runner.design_metrics", **ids):
-                m_spec = traced.metrics(jnp.asarray(
-                    spec["genomes"][j_best]))
+            r0 = n_main + j_best * W
+            m_spec = _rows(table, r0, r0 + W)
             with span("runner.write_artifacts", **ids):
-                energy = np.asarray(m_spec.energy)
-                latency = np.asarray(m_spec.latency)
                 for i, n in enumerate(names):
                     sub = {
                         "design": space.decode(spec["genomes"][j_best, i]),
                         "objective_score": float(
                             spec["best_scores"][j_best, i]),
-                        "area_mm2": float(np.asarray(m_spec.area)[i]),
-                        "feasible": bool(
-                            np.asarray(m_spec.feasible_w)[i, i]),
+                        "area_mm2": float(m_spec["area"][i]),
+                        "feasible": bool(m_spec["feasible_w"][i, i]),
                         "per_workload": {
-                            n: {"energy_mJ": float(energy[i, i]) * 1e3,
-                                "latency_ms": float(latency[i, i]) * 1e3,
-                                "edap": float(spec["edap"][j_best, i])}},
+                            n: {"energy_mJ": float(
+                                    m_spec["energy"][i, i]) * 1e3,
+                                "latency_ms": float(
+                                    m_spec["latency"][i, i]) * 1e3,
+                                "edap": float(spec_edap[j_best, i])}},
                         "best_score": float(spec["best_scores"][j_best, i]),
                         "seed": seed,
                     }

@@ -130,7 +130,7 @@ class CodesignService:
             "submitted", "completed", "cancelled", "expired", "failed",
             "result_cache_hits", "batches", "buckets",
             "degraded_buckets", "lanes_total", "lanes_padded",
-            "dispatched")}
+            "dispatched", "design_calls")}
         # cumulative seconds: submit -> batch, and inside _execute
         self._queue_wait_s = 0.0
         self._batch_s = 0.0
@@ -252,7 +252,8 @@ class CodesignService:
             kernel_cache_hit_rate=(kh / (kh + km) if kh + km else 0.0),
             latency_p50_s=pct(50), latency_p90_s=pct(90),
             latency_p99_s=pct(99), dispatched=c["dispatched"],
-            queue_wait_s=queue_wait, batch_s=batch_s)
+            queue_wait_s=queue_wait, batch_s=batch_s,
+            design_calls=c["design_calls"])
 
     def close(self, drain: bool = True) -> None:
         """Stop the service. ``drain=True`` (default) finishes every
@@ -286,12 +287,15 @@ class CodesignService:
             if batch:
                 with self._cond:
                     self._counts["batches"] += 1
+                calls = runner.design_calls()
                 # space-joined: the profiler splits span ids on commas
                 with span("service.batch", requests=" ".join(
                         r.rid for r in batch)) as sp:
                     self._execute(batch)
                 with self._cond:
                     self._batch_s += sp.seconds
+                    self._counts["design_calls"] += (runner.design_calls()
+                                                     - calls)
 
     def _collect(self) -> List[_Record]:
         """Close the window: pop up to max_batch queued records,

@@ -10,6 +10,7 @@ import pytest
 
 from repro.core import make_objective, random_search, get_space
 from repro.core import Calib, ScorerSpec, build_scorer
+from repro.experiments.runner import design_table
 from repro.experiments import (Budget, Scenario, compute_gap,
                                baseline_reductions, get_scenario,
                                render_markdown,
@@ -205,8 +206,13 @@ def test_specific_fanout_matches_sequential(objective, tech):
     seeds = [0, 1]
     fan = run_specific_fanout(sc, space, traced, seeds, len(wls))
     seq = run_specific_sequential(sc, space, obj, wls, seeds)
-    assert fan["edap"].shape == (2, len(wls))
-    np.testing.assert_allclose(fan["edap"], seq["edap"], rtol=1e-4)
+    # the fan-out's designs on the full-set scorer, each read on its
+    # own workload's column (what finalize_result reports)
+    W = len(wls)
+    assert fan["genomes"].shape[:2] == (2, W)
+    edap = design_table(sc, traced, fan["genomes"].reshape(2 * W, -1))[
+        "edap"].reshape(2, W, W)[:, np.arange(W), np.arange(W)]
+    np.testing.assert_allclose(edap, seq["edap"], rtol=1e-4)
     np.testing.assert_allclose(fan["best_scores"], seq["best_scores"],
                                rtol=1e-4)
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.experiments import campaign, get_scenario
+from repro.experiments import campaign, get_scenario, runner
 from repro.kernels.imc_fused import imc_fused_gemm
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -98,5 +98,27 @@ def test_search_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
             for a in bucket._main_arrays()]
     compiled = kern.lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == fused
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("name,fused", [
+    ("rram_small_set", False),
+    ("rram_accuracy", True),
+])
+def test_design_table_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       monkeypatch, name, fused):
+    """Finalize's design table at the 64-row tier an 8-seed campaign of
+    the paper's small set fills (8 seeds + 32 specific designs), as the
+    chip builds it (see test_search_kernel_compiles_for_v5e)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jobs = campaign.plan_campaign([get_scenario(name)], n_seeds=8,
+                                  write=False, force=True)
+    traced = jobs[0].traced
+    n = jobs[0].setup.space.n_params
+    rows = runner._design_rows(8 + 8 * jobs[0].n_workloads)
+    assert rows == 64
+    genomes = jax.ShapeDtypeStruct((rows, n), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(traced.design).lower(genomes).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == fused
     _fits_one_chip(compiled)
