@@ -120,6 +120,8 @@ class ServiceStats:
     batch_s: float = 0.0              # time inside batches (plan to drain)
     design_calls: int = 0             # compiled design-table calls of
                                       # finalize: one per finalized job
+    cost_rows_evaluated: int = 0      # cost-model layer rows its lanes
+    cost_rows_used: int = 0           # evaluate / their objectives read
 
     def asdict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)

@@ -138,6 +138,20 @@ class CampaignJob:
     def n_lanes(self) -> int:
         return len(self.seeds) + self.n_spec
 
+    def cost_rows(self) -> Tuple[int, int]:
+        """(evaluated, used) cost-model layer rows of the job's lanes:
+        each lane's design evaluations (P_H + P_GA a generation, the
+        budget's count) times the rows its scorer evaluates, and times
+        the rows of the workloads its objective reads. A seed's lane
+        reads every row; its specific lane for workload w evaluates
+        every row too but reads only w's."""
+        evals = self.p_h + self.scenario.budget.p_ga * self.sched.shape[0]
+        rows = self.setup.layer_rows
+        total, S = sum(rows), len(self.seeds)
+        spec_used = S * total if self.wants_spec else 0
+        return (evals * (S * total + self.n_spec * total),
+                evals * (S * total + spec_used))
+
     def bucket_key(self) -> Tuple:
         sc = self.scenario
         return (self.engine, runner.scorer_key(sc), self.p_h, self.p_e,
@@ -288,12 +302,17 @@ class _Bucket:
         self.finalize_s = 0.0
         self.drain_s = 0.0
         self.design_calls = 0   # design-table calls of its finalizes
+        self.cost_rows_evaluated = 0
+        self.cost_rows_used = 0
 
     def add(self, job: CampaignJob) -> None:
         self.offsets.append((self.n_main, self.n_spec))
         self.jobs.append(job)
         self.n_main += len(job.seeds)
         self.n_spec += job.n_spec
+        evaluated, used = job.cost_rows()
+        self.cost_rows_evaluated += evaluated
+        self.cost_rows_used += used
 
     @property
     def n_lanes(self) -> int:
@@ -700,7 +719,9 @@ def run_campaign(scenarios: Sequence[Scenario],
              "wait_s": b.wait_s,
              "finalize_s": b.finalize_s,
              "drain_s": b.drain_s,
-             "design_calls": b.design_calls}
+             "design_calls": b.design_calls,
+             "cost_rows_evaluated": b.cost_rows_evaluated,
+             "cost_rows_used": b.cost_rows_used}
             for b in buckets.values()],
     }
     if write:

@@ -796,6 +796,15 @@ class ScenarioSetup:
     def is_mo(self) -> bool:
         return isinstance(self.objective, MultiObjective)
 
+    @property
+    def layer_rows(self) -> Tuple[int, ...]:
+        """Cost-model layer rows of each workload: its layers on the
+        flat axis, or the builder's padded depth on the joint path."""
+        if self.builder is not None:
+            return (self.builder.lmax,) * self.builder.n_workloads
+        return tuple(int(n) for n in np.bincount(
+            self.wa.seg_ids, minlength=self.wa.n_workloads))
+
 
 def setup_scenario(scenario: Scenario) -> ScenarioSetup:
     """Resolve a scenario's space/workloads/objective (no device work)."""

@@ -130,7 +130,8 @@ class CodesignService:
             "submitted", "completed", "cancelled", "expired", "failed",
             "result_cache_hits", "batches", "buckets",
             "degraded_buckets", "lanes_total", "lanes_padded",
-            "dispatched", "design_calls")}
+            "dispatched", "design_calls", "cost_rows_evaluated",
+            "cost_rows_used")}
         # cumulative seconds: submit -> batch, and inside _execute
         self._queue_wait_s = 0.0
         self._batch_s = 0.0
@@ -253,7 +254,9 @@ class CodesignService:
             latency_p50_s=pct(50), latency_p90_s=pct(90),
             latency_p99_s=pct(99), dispatched=c["dispatched"],
             queue_wait_s=queue_wait, batch_s=batch_s,
-            design_calls=c["design_calls"])
+            design_calls=c["design_calls"],
+            cost_rows_evaluated=c["cost_rows_evaluated"],
+            cost_rows_used=c["cost_rows_used"])
 
     def close(self, drain: bool = True) -> None:
         """Stop the service. ``drain=True`` (default) finishes every
@@ -345,6 +348,9 @@ class CodesignService:
                 b.n_lanes for b in buckets.values())
             self._counts["lanes_padded"] += sum(
                 b.lanes_padded_to - b.n_lanes for b in buckets.values())
+            for k in ("cost_rows_evaluated", "cost_rows_used"):
+                self._counts[k] += sum(getattr(b, k)
+                                       for b in buckets.values())
 
         def on_drained(bucket) -> None:
             for job in bucket.jobs:

@@ -39,6 +39,10 @@ TINY_MO = dataclasses.replace(TINY, name="tiny_campaign_mo",
                               objective="edap:mean+cost",
                               specific_baselines=False)
 TINY_B = dataclasses.replace(TINY, name="tiny_campaign_b")
+TINY_ONE = dataclasses.replace(TINY, name="tiny_campaign_one",
+                               workloads=("alexnet",))
+TINY_THREE = dataclasses.replace(TINY, name="tiny_campaign_three",
+                                 workloads=("alexnet", "resnet18", "vgg16"))
 
 TIMING_FIELDS = {"wall_time_s", "search_wall_time_s",
                  "sampling_time_s"}
@@ -349,3 +353,54 @@ def test_campaign_persistent_cache_index(tmp_path, monkeypatch):
         # tmp_path is deleted after the test: don't leave jax's
         # on-disk cache pointed at it for the rest of the session
         jax.config.update("jax_compilation_cache_dir", None)
+
+
+# ---------------------------------------------------------------------------
+# cost-model row counters
+# ---------------------------------------------------------------------------
+
+
+def _rows_per_lane(sc: Scenario) -> int:
+    """Design evaluations a lane's budget prescribes times the flat
+    layer axis of the scenario's workload set."""
+    b = sc.budget
+    evals = b.p_h + b.p_ga * 4 * b.generations   # four phases of G
+    return evals * sum(w.n_layers for w in get_workload_set(sc.workloads))
+
+
+@pytest.mark.parametrize("sc", [TINY_ONE, TINY, TINY_THREE],
+                         ids=lambda sc: sc.name)
+def test_cost_row_counters(tmp_path, sc):
+    """A seed's lane reads every row it evaluates; a specific lane
+    evaluates all W workloads' rows and reads one's. So used/evaluated
+    is 1 for one workload, and exactly (1+1)/(1+W) for W workloads with
+    specific baselines."""
+    S, W = 2, len(sc.workloads)
+    _, stats = campaign.run_campaign([sc], out_dir=str(tmp_path),
+                                     n_seeds=S, force=True)
+    b, = stats["buckets"]
+    spec_lanes = S * W if W > 1 else 0
+    assert b["lanes"] == S + spec_lanes
+    assert b["cost_rows_evaluated"] == (S + spec_lanes) * _rows_per_lane(sc)
+    if W == 1:
+        assert b["cost_rows_used"] == b["cost_rows_evaluated"]
+    else:
+        assert b["cost_rows_used"] * (1 + W) == 2 * b["cost_rows_evaluated"]
+
+
+def test_service_sums_cost_row_counters(tmp_path):
+    """ServiceStats carries the counters summed over its buckets: each
+    one-seed request of a W-workload scenario adds (1 + W) lanes."""
+    from repro.api import CodesignService, SearchRequest
+    svc = CodesignService(out_dir=str(tmp_path), force=True, window_s=0.05)
+    try:
+        rids = [svc.submit(SearchRequest(TINY, seed=s, n_seeds=1))
+                for s in (1, 2)]
+        for rid in rids:
+            assert svc.result(rid, timeout=600).status == "completed"
+    finally:
+        svc.close()
+    st = svc.stats()
+    per_request = (1 + 2) * _rows_per_lane(TINY)
+    assert st.cost_rows_evaluated == 2 * per_request
+    assert st.cost_rows_used * 3 == 2 * st.cost_rows_evaluated

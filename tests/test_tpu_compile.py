@@ -12,8 +12,10 @@ imports this file.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.experiments import campaign, get_scenario, runner
 from repro.kernels.imc_fused import imc_fused_gemm
@@ -121,4 +123,34 @@ def test_design_table_compiles_for_v5e(one_chip, no_persistent_cache,
     genomes = jax.ShapeDtypeStruct((rows, n), jnp.int32, sharding=one_chip)
     compiled = jax.jit(traced.design).lower(genomes).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == fused
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("part", ["main", "spec"])
+def test_large_set_lanes_compile_sharded_for_v5e(topo, no_persistent_cache,
+                                                 monkeypatch, part):
+    """The paper-budget large set's two lane kernels (8 seeds; 72
+    specific lanes padded to 96), sharded over a described 2x2 v5e by
+    shard_map as the four-chip cell runs them: each chip runs its lanes
+    with no collective."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jobs = campaign.plan_campaign([get_scenario("rram_large_set")],
+                                  n_seeds=8, write=False, force=True)
+    (bucket,) = campaign.bucket_jobs(jobs).values()
+    job = bucket.jobs[0]
+    assert (bucket.n_main, bucket.n_spec) == (8, 72)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    kern = campaign._build_bucket_kernel(bucket.key, job.traced,
+                                         job.setup.space, mesh, part)
+    arrays = (bucket._main_arrays() if part == "main"
+              else bucket._spec_arrays())
+    assert arrays[0].shape[0] == {"main": 8, "spec": 96}[part]
+    lanes = NamedSharding(mesh, PartitionSpec("data"))
+    compiled = kern.lower(*[jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=lanes)
+                            for a in arrays]).compile()
+    text = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op
     _fits_one_chip(compiled)
