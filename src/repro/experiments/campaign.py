@@ -106,6 +106,23 @@ def lane_tier(b: int) -> int:
     return -(-_tier(b, LANE_TIERS, 128) // n_dev) * n_dev
 
 
+def lane_keys(seeds: Sequence[int]) -> np.ndarray:
+    """The ``(n, 2) uint32`` stack of ``jax.random.PRNGKey(s)`` for the
+    integer seeds, built in NumPy: no host-to-device transfer, no device
+    call and no read-back per lane.
+
+    Assumes JAX's default PRNG implementation, threefry2x32 (and no
+    ``jax_random_seed_offset``), whose key is the seed bit-cast to two
+    words: ``[s >> 32, s]`` of its 64-bit value with x64 on, and
+    ``[0, s]`` of its 32-bit value with x64 off.
+    """
+    s = np.asarray(seeds, np.int64).view(np.uint64)
+    lo = (s & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    hi = ((s >> np.uint64(32)).astype(np.uint32) if jax.config.jax_enable_x64
+          else np.zeros_like(lo))
+    return np.stack([hi, lo], axis=1)
+
+
 @dataclasses.dataclass
 class CampaignJob:
     """One scenario run inside a campaign."""
@@ -350,37 +367,34 @@ class _Bucket:
         return tuple(c + c[:1] * (tier - n) for c in cols)
 
     def _main_arrays(self) -> Tuple[np.ndarray, ...]:
-        keys, scheds, actives = [], [], []
+        seeds, scheds, actives = [], [], []
         for job in self.jobs:
             pad, act = self._padded_sched(job)
-            keys += [jax.random.PRNGKey(s) for s in job.seeds]
+            seeds += job.seeds
             scheds += [pad] * len(job.seeds)
             actives += [act] * len(job.seeds)
-        keys, scheds, actives = self._pad_lanes(
-            [keys, scheds, actives], self.n_main,
+        seeds, scheds, actives = self._pad_lanes(
+            [seeds, scheds, actives], self.n_main,
             lane_tier(self.n_main))
-        return (np.stack([np.asarray(k) for k in keys]),
-                np.stack(scheds), np.stack(actives))
+        return lane_keys(seeds), np.stack(scheds), np.stack(actives)
 
     def _spec_arrays(self) -> Tuple[np.ndarray, ...]:
-        keys, ws, scheds, actives = [], [], [], []
+        seeds, ws, scheds, actives = [], [], [], []
         for job in self.jobs:
             if not job.wants_spec:
                 continue
             pad, act = self._padded_sched(job)
             W = job.n_workloads
-            lane_keys = [jax.random.PRNGKey(s + 1000 + i)
-                         for s in job.seeds for i in range(W)]
-            keys += lane_keys
+            job_seeds = [s + 1000 + i for s in job.seeds for i in range(W)]
+            seeds += job_seeds
             ws += [i for _ in job.seeds for i in range(W)]
-            scheds += [pad] * len(lane_keys)
-            actives += [act] * len(lane_keys)
-        keys, ws, scheds, actives = self._pad_lanes(
-            [keys, ws, scheds, actives], self.n_spec,
+            scheds += [pad] * len(job_seeds)
+            actives += [act] * len(job_seeds)
+        seeds, ws, scheds, actives = self._pad_lanes(
+            [seeds, ws, scheds, actives], self.n_spec,
             lane_tier(self.n_spec))
-        return (np.stack([np.asarray(k) for k in keys]),
-                np.asarray(ws, np.int32), np.stack(scheds),
-                np.stack(actives))
+        return (lane_keys(seeds), np.asarray(ws, np.int32),
+                np.stack(scheds), np.stack(actives))
 
     def _kernel_key(self, part: str, n_lanes: int) -> Tuple:
         """(kernel-cache key, mesh) of one lane flavor's kernel."""

@@ -193,6 +193,101 @@ def test_padding_property_hypothesis(space_scorer):
 
 
 # ---------------------------------------------------------------------------
+# lane PRNG keys, built on the host
+# ---------------------------------------------------------------------------
+
+LANE_SEEDS = (0, 1, 2 ** 31 - 1, 2 ** 31, 2 ** 31 + 1031, 2 ** 32 - 1,
+              2 ** 32 + 5, -1, -2 ** 31, 27182818280000,
+              27182818280000 % 2 ** 31, (31415926 * 10 ** 4 + 7) % 2 ** 31)
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+@pytest.mark.parametrize("seed", LANE_SEEDS)
+def test_lane_keys_match_prngkey(seed, x64):
+    with jax.enable_x64(x64):
+        want = np.stack([np.asarray(jax.random.PRNGKey(s))
+                         for s in (seed, seed + 1000)])
+        got = campaign.lane_keys([seed, seed + 1000])
+    assert got.dtype == want.dtype == np.uint32
+    assert got.shape == want.shape == (2, 2)
+    np.testing.assert_array_equal(got, want)
+
+
+TINY_FOUR = dataclasses.replace(
+    TINY, name="tiny_campaign_four",
+    workloads=("alexnet", "resnet18", "vgg16", "mobilenetv3"))
+
+
+@pytest.fixture(scope="module")
+def lane_bucket():
+    """Two jobs of one bucket, 3 + 2 seeds over 4 workloads: 5 main
+    lanes pad to the tier 6 and 20 specific lanes to 24."""
+    jobs = (campaign.plan_campaign([TINY_FOUR], seed=7, n_seeds=3,
+                                   write=False)
+            + campaign.plan_campaign([TINY_FOUR], seed=2 ** 31 + 1031,
+                                     n_seeds=2, write=False))
+    bucket = campaign._Bucket(jobs[0].bucket_key())
+    for job in jobs:
+        assert job.bucket_key() == bucket.key
+        bucket.add(job)
+    assert (campaign.lane_tier(bucket.n_main),
+            campaign.lane_tier(bucket.n_spec)) == (6, 24)
+    return bucket
+
+
+def _old_lane_arrays(bucket):
+    """The per-key construction the lane arrays replaced: one
+    ``jax.random.PRNGKey`` and one read-back per lane."""
+    mk, ms, ma, sk, sw, ss, sa = [], [], [], [], [], [], []
+    for job in bucket.jobs:
+        pad, act = bucket._padded_sched(job)
+        for s in job.seeds:
+            mk.append(jax.random.PRNGKey(s))
+            ms.append(pad)
+            ma.append(act)
+        for s in job.seeds:
+            for i in range(job.n_workloads):
+                sk.append(jax.random.PRNGKey(s + 1000 + i))
+                sw.append(i)
+                ss.append(pad)
+                sa.append(act)
+
+    def pad_to(col, tier):
+        return col + col[:1] * (tier - len(col))
+
+    main = [pad_to(c, campaign.lane_tier(len(mk))) for c in (mk, ms, ma)]
+    spec = [pad_to(c, campaign.lane_tier(len(sk)))
+            for c in (sk, sw, ss, sa)]
+    return ((np.stack([np.asarray(k) for k in main[0]]),
+             np.stack(main[1]), np.stack(main[2])),
+            (np.stack([np.asarray(k) for k in spec[0]]),
+             np.asarray(spec[1], np.int32), np.stack(spec[2]),
+             np.stack(spec[3])))
+
+
+def test_lane_arrays_match_per_key_construction(lane_bucket):
+    old_main, old_spec = _old_lane_arrays(lane_bucket)
+    for new, old in ((lane_bucket._main_arrays(), old_main),
+                     (lane_bucket._spec_arrays(), old_spec)):
+        assert len(new) == len(old)
+        for a, b in zip(new, old):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+    assert old_main[0].shape == (6, 2) and old_spec[0].shape == (24, 2)
+
+
+def test_lane_arrays_make_no_device_transfer(lane_bucket):
+    """Building a bucket's lane arrays moves nothing between host and
+    device; the per-key construction it replaced sent every seed to the
+    device, which the same guard refuses."""
+    with jax.transfer_guard("disallow"):
+        lane_bucket._main_arrays()
+        lane_bucket._spec_arrays()
+        with pytest.raises(Exception, match="[Dd]isallowed"):
+            _old_lane_arrays(lane_bucket)
+
+
+# ---------------------------------------------------------------------------
 # the in-process kernel cache: LRU bound + counters
 # ---------------------------------------------------------------------------
 
